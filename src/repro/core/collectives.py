@@ -42,7 +42,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core import compression as comp
-from repro.core import prims
 from repro.core.schedule import (AllGather, AllToAll, CommSchedule, Psum,
                                  ReduceScatter, SlowChunk, SyncConfig,
                                  all_to_all_from_axes, build_schedule,
@@ -127,8 +126,7 @@ def _schedule_usable(schedule: Optional[CommSchedule], x: jax.Array,
 
 
 def _slow_chunk_psum(leg: SlowChunk, x_flat: jax.Array,
-                     ef_flat: Optional[jax.Array], cfg: SyncConfig,
-                     ranks: prims.Ranks
+                     ef_flat: Optional[jax.Array], cfg: SyncConfig
                      ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """Lower ONE slow-tier sub-flow (this is the only leg kind where the
     Section codec runs)."""
@@ -137,16 +135,13 @@ def _slow_chunk_psum(leg: SlowChunk, x_flat: jax.Array,
     assert leg.codec == cfg.codec, (leg.codec, cfg.codec)
     codec = cfg.make_codec()
     if isinstance(codec, comp.Int8Codec):
-        return comp.compressed_psum_int8(x_flat, leg.axis, codec, ef_flat,
-                                         ranks=ranks)
+        return comp.compressed_psum_int8(x_flat, leg.axis, codec, ef_flat)
     if isinstance(codec, comp.TopKCodec):
-        return comp.compressed_psum_topk(x_flat, leg.axis, codec, ef_flat,
-                                         ranks=ranks)
+        return comp.compressed_psum_topk(x_flat, leg.axis, codec, ef_flat)
     raise ValueError(leg.codec)
 
 
-def _psum_leg(leg: Psum, x: jax.Array, cfg: SyncConfig,
-              ranks: prims.Ranks) -> jax.Array:
+def _psum_leg(leg: Psum, x: jax.Array, cfg: SyncConfig) -> jax.Array:
     """Lower one unscattered (mid-tier / flat) psum leg."""
     if leg.codec is None:
         return lax.psum(x, leg.axis)
@@ -155,25 +150,23 @@ def _psum_leg(leg: Psum, x: jax.Array, cfg: SyncConfig,
     assert leg.codec == cfg.mid_codec, (leg.codec, cfg.mid_codec)
     shp = x.shape
     out, _ = comp.compressed_psum_int8(x.reshape(-1), leg.axis,
-                                       cfg.make_mid_codec(), None,
-                                       ranks=ranks)
+                                       cfg.make_mid_codec(), None)
     return out.reshape(shp)
 
 
-def _rs_leg(leg: ReduceScatter, x: jax.Array, dim: int, cfg: SyncConfig,
-            ranks: prims.Ranks) -> jax.Array:
+def _rs_leg(leg: ReduceScatter, x: jax.Array, dim: int,
+            cfg: SyncConfig) -> jax.Array:
     """Lower one fast-tier reduce-scatter leg (scattered mid-tier legs may
     carry the mid codec — int8 without error feedback, like mid psums)."""
     if leg.codec is None:
-        return prims.reduce_scatter_tiled(x, leg.axis, dim)
+        return lax.psum_scatter(x, leg.axis, scatter_dimension=dim, tiled=True)
     assert leg.codec == cfg.mid_codec, (leg.codec, cfg.mid_codec)
     return comp.compressed_reduce_scatter_int8(x, leg.axis,
-                                               cfg.make_mid_codec(), dim,
-                                               ranks=ranks)
+                                               cfg.make_mid_codec(), dim)
 
 
 def _slow_group(legs: Sequence[SlowChunk], x: jax.Array,
-                ef: Optional[jax.Array], cfg: SyncConfig, ranks: prims.Ranks
+                ef: Optional[jax.Array], cfg: SyncConfig
                 ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """Sequentially lower a contiguous run of slow chunks over the
     flattened shard (the non-pipelined slow leg).
@@ -192,7 +185,7 @@ def _slow_group(legs: Sequence[SlowChunk], x: jax.Array,
     nefs: List = [None] * C
     for leg in legs:
         o, ne = _slow_chunk_psum(leg, parts[leg.index], ef_parts[leg.index],
-                                 cfg, ranks)
+                                 cfg)
         outs[leg.index] = o
         nefs[leg.index] = ne
     out = jnp.concatenate(outs) if C > 1 else outs[0]
@@ -204,7 +197,7 @@ def _slow_group(legs: Sequence[SlowChunk], x: jax.Array,
 
 
 def _apply_down(legs: Sequence, x: jax.Array, dim: int, cfg: SyncConfig,
-                ranks: prims.Ranks, log: Optional[List]) -> jax.Array:
+                log: Optional[List]) -> jax.Array:
     """Lower the down phase (ReduceScatter / Psum legs), coalescing runs of
     codec-less psums into one ``lax.psum`` call."""
     pend: List[Psum] = []
@@ -223,9 +216,9 @@ def _apply_down(legs: Sequence, x: jax.Array, dim: int, cfg: SyncConfig,
             continue
         flush()
         if isinstance(leg, ReduceScatter):
-            x = _rs_leg(leg, x, dim, cfg, ranks)
+            x = _rs_leg(leg, x, dim, cfg)
         elif isinstance(leg, Psum):
-            x = _psum_leg(leg, x, cfg, ranks)
+            x = _psum_leg(leg, x, cfg)
         else:
             raise TypeError(leg)
         if log is not None:
@@ -235,27 +228,27 @@ def _apply_down(legs: Sequence, x: jax.Array, dim: int, cfg: SyncConfig,
 
 
 def _lower_sequential(schedule: CommSchedule, x: jax.Array,
-                      ef: Optional[jax.Array], ranks: prims.Ranks,
+                      ef: Optional[jax.Array],
                       log: Optional[List], *, gather_up: bool = True
                       ) -> Tuple[jax.Array, Optional[jax.Array]]:
     dim = max(schedule.scatter_dim, 0)
     cfg = schedule.cfg
-    x = _apply_down(schedule.down_legs, x, dim, cfg, ranks, log)
+    x = _apply_down(schedule.down_legs, x, dim, cfg, log)
     slow = schedule.slow_legs
     if slow:
-        x, ef = _slow_group(slow, x, ef, cfg, ranks)
+        x, ef = _slow_group(slow, x, ef, cfg)
         if log is not None:
             log.extend(slow)
     if gather_up:
         for leg in schedule.up_legs:
-            x = prims.all_gather_tiled(x, leg.axis, dim, ranks)
+            x = lax.all_gather(x, leg.axis, axis=dim, tiled=True)
             if log is not None:
                 log.append(leg)
     return x, ef
 
 
 def _lower_pipelined(schedule: CommSchedule, x: jax.Array,
-                     ef: Optional[jax.Array], ranks: prims.Ranks,
+                     ef: Optional[jax.Array],
                      log: Optional[List]
                      ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """The overlapped slow-leg pipeline.
@@ -292,7 +285,7 @@ def _lower_pipelined(schedule: CommSchedule, x: jax.Array,
     slow_log: List = [] if log is not None else None
     up_log: List = [] if log is not None else None
 
-    shards = [_apply_down(down, p, dim, cfg, ranks,
+    shards = [_apply_down(down, p, dim, cfg,
                           down_log if i == 0 else None)
               for i, p in enumerate(parts)]
     shard_shape = shards[0].shape
@@ -302,7 +295,7 @@ def _lower_pipelined(schedule: CommSchedule, x: jax.Array,
         # (lane_offset rotation — see CommSchedule.with_lane_offset)
         leg = slow[pos]
         o, ne = _slow_chunk_psum(leg, shards[leg.index].reshape(-1),
-                                 ef_parts[leg.index], cfg, ranks)
+                                 ef_parts[leg.index], cfg)
         if slow_log is not None:
             slow_log.append(leg)
         return leg.index, o, ne
@@ -310,7 +303,7 @@ def _lower_pipelined(schedule: CommSchedule, x: jax.Array,
     def gather(buf: jax.Array, lg) -> jax.Array:
         y = buf.reshape(shard_shape)
         for leg in up:
-            y = prims.all_gather_tiled(y, leg.axis, dim, ranks)
+            y = lax.all_gather(y, leg.axis, axis=dim, tiled=True)
             if lg is not None:
                 lg.append(leg)
         return y
@@ -339,7 +332,6 @@ def _lower_pipelined(schedule: CommSchedule, x: jax.Array,
 
 def lower_all_reduce(schedule: CommSchedule, x: jax.Array,
                      ef: Optional[jax.Array] = None,
-                     ranks: prims.Ranks = None,
                      leg_log: Optional[List] = None
                      ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """Lower a full all-reduce schedule to JAX ops.
@@ -354,13 +346,12 @@ def lower_all_reduce(schedule: CommSchedule, x: jax.Array,
     if not schedule.legs:
         return x, ef
     if schedule.pipelined and schedule.chunks > 1:
-        return _lower_pipelined(schedule, x, ef, ranks, leg_log)
-    return _lower_sequential(schedule, x, ef, ranks, leg_log)
+        return _lower_pipelined(schedule, x, ef, leg_log)
+    return _lower_sequential(schedule, x, ef, leg_log)
 
 
 def lower_reduce_scatter(schedule: CommSchedule, x: jax.Array,
                          ef: Optional[jax.Array] = None,
-                         ranks: prims.Ranks = None,
                          leg_log: Optional[List] = None
                          ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """Lower only the down half of a schedule (fast-tier reduce-scatters +
@@ -369,8 +360,7 @@ def lower_reduce_scatter(schedule: CommSchedule, x: jax.Array,
     assert schedule.strategy == "hier_striped", schedule.strategy
     assert not any(isinstance(l, Psum) for l in schedule.down_legs), \
         "ZeRO-1 sections must scatter every fast tier"
-    return _lower_sequential(schedule, x, ef, ranks, leg_log,
-                             gather_up=False)
+    return _lower_sequential(schedule, x, ef, leg_log, gather_up=False)
 
 
 # ---------------------------------------------------------------------------
@@ -380,7 +370,6 @@ def lower_reduce_scatter(schedule: CommSchedule, x: jax.Array,
 
 def pod_psum(x: jax.Array, slow_axis: Optional[str], cfg: SyncConfig,
              ef: Optional[jax.Array] = None,
-             ranks: prims.Ranks = None,
              lane_offset: int = 0
              ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """All-reduce ``x`` (this chip's fast-tier-scattered shard) over the
@@ -397,14 +386,13 @@ def pod_psum(x: jax.Array, slow_axis: Optional[str], cfg: SyncConfig,
         chunks -= 1
     legs = [SlowChunk((j + lane_offset) % chunks, chunks, cfg.codec,
                       slow_axis, slow_axis, n) for j in range(chunks)]
-    return _slow_group(legs, x, ef, cfg, ranks)
+    return _slow_group(legs, x, ef, cfg)
 
 
 def dfabric_all_reduce(x: jax.Array, fast_axis: Optional[Axes],
                        slow_axis: Optional[str],
                        cfg: SyncConfig, scatter_dim: int = 0,
                        ef: Optional[jax.Array] = None,
-                       ranks: prims.Ranks = None,
                        schedule: Optional[CommSchedule] = None,
                        leg_log: Optional[List] = None,
                        lane_offset: int = 0,
@@ -426,14 +414,13 @@ def dfabric_all_reduce(x: jax.Array, fast_axis: Optional[Axes],
     if not _schedule_usable(schedule, x, fast, slow_axis):
         schedule = _trace_schedule(fast, slow_axis, cfg, x.shape, scatter_dim,
                                    lane_offset, staging)
-    return lower_all_reduce(schedule, x, ef=ef, ranks=ranks, leg_log=leg_log)
+    return lower_all_reduce(schedule, x, ef=ef, leg_log=leg_log)
 
 
 def dfabric_reduce_scatter(x: jax.Array, fast_axis: Axes,
                            slow_axis: Optional[str],
                            cfg: SyncConfig, scatter_dim: int = 0,
                            ef: Optional[jax.Array] = None,
-                           ranks: prims.Ranks = None,
                            schedule: Optional[CommSchedule] = None,
                            leg_log: Optional[List] = None,
                            lane_offset: int = 0,
@@ -450,20 +437,18 @@ def dfabric_reduce_scatter(x: jax.Array, fast_axis: Axes,
         full = _dc_replace(cfg, scatter_depth=-1)
         schedule = _trace_schedule(fast, slow_axis, full, x.shape,
                                    scatter_dim, lane_offset, staging)
-    return lower_reduce_scatter(schedule, x, ef=ef, ranks=ranks,
-                                leg_log=leg_log)
+    return lower_reduce_scatter(schedule, x, ef=ef, leg_log=leg_log)
 
 
 def dfabric_all_gather(x: jax.Array, fast_axis: Axes,
-                       gather_dim: int = 0,
-                       ranks: prims.Ranks = None) -> jax.Array:
+                       gather_dim: int = 0) -> jax.Array:
     """All-gather over the fast tiers, undoing
     :func:`dfabric_reduce_scatter`'s ownership order (gathers run in
     reverse tier order so the fastest tier ends up major)."""
     fast = normalize_axes(fast_axis)
     for a in reversed(fast):
         if axis_size(a) > 1:
-            x = prims.all_gather_tiled(x, a, gather_dim, ranks)
+            x = lax.all_gather(x, a, axis=gather_dim, tiled=True)
     return x
 
 

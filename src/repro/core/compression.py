@@ -24,8 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core import prims
-from repro.utils import jax_compat
+from repro.utils.jax_compat import axis_size
 
 
 @dataclass(frozen=True)
@@ -69,7 +68,7 @@ class TopKCodec:
     def encode(self, x: jax.Array) -> Tuple[jax.Array, jax.Array]:
         n = x.shape[0]
         k = self.k_of(n)
-        vals, idx = jax_compat.top_k(jnp.abs(x), k)
+        vals, idx = lax.top_k(jnp.abs(x), k)
         del vals
         return x[idx], idx.astype(jnp.int32)
 
@@ -90,8 +89,7 @@ class TopKCodec:
 
 
 def compressed_psum_int8(x: jax.Array, axis_name: str, codec: Int8Codec,
-                         ef: Optional[jax.Array] = None,
-                         ranks: prims.Ranks = None
+                         ef: Optional[jax.Array] = None
                          ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """Sum ``x`` over ``axis_name`` transferring int8 on the wire.
 
@@ -109,16 +107,15 @@ def compressed_psum_int8(x: jax.Array, axis_name: str, codec: Int8Codec,
     xp = jnp.concatenate([x, jnp.zeros((pad,), x.dtype)]) if pad else x
     q, s = codec.encode(xp)
     new_ef = (xp - codec.decode(q, s))[:n0] if ef is not None else None
-    qg = prims.all_gather_stacked(q, axis_name, ranks)  # (P, n) int8 on the wire
-    sg = prims.all_gather_stacked(s, axis_name, ranks)  # (P, n/block) f32
+    qg = lax.all_gather(q, axis_name)  # (P, n) int8 on the wire
+    sg = lax.all_gather(s, axis_name)  # (P, n/block) f32
     dec = jax.vmap(lambda qq, ss: codec.decode(qq, ss))(qg, sg)
     out = jnp.sum(dec, axis=0)[:n0].astype(x.dtype)
     return out, new_ef
 
 
 def compressed_reduce_scatter_int8(x: jax.Array, axis_name: str,
-                                   codec: Int8Codec, dim: int,
-                                   ranks: prims.Ranks = None) -> jax.Array:
+                                   codec: Int8Codec, dim: int) -> jax.Array:
     """Reduce-scatter ``x`` over ``axis_name`` along ``dim`` transferring
     int8 on the wire (tiled: member *i* keeps slice *i* of the sum, the
     same ownership order as ``lax.psum_scatter(..., tiled=True)``).
@@ -131,7 +128,7 @@ def compressed_reduce_scatter_int8(x: jax.Array, axis_name: str,
     own residual every step; a scattered leg's residual would belong to
     a different shard each step).
     """
-    n = jax_compat.axis_size(axis_name)
+    n = axis_size(axis_name)
     shp = x.shape
     assert shp[dim] % n == 0, (shp, dim, n)
     xf = x.reshape(-1)
@@ -139,26 +136,25 @@ def compressed_reduce_scatter_int8(x: jax.Array, axis_name: str,
     pad = (-n0) % codec.block
     xp = jnp.concatenate([xf, jnp.zeros((pad,), xf.dtype)]) if pad else xf
     q, s = codec.encode(xp)
-    qg = prims.all_gather_stacked(q, axis_name, ranks)  # (P, n) int8 wire
-    sg = prims.all_gather_stacked(s, axis_name, ranks)  # (P, n/block) f32
+    qg = lax.all_gather(q, axis_name)  # (P, n) int8 wire
+    sg = lax.all_gather(s, axis_name)  # (P, n/block) f32
     dec = jax.vmap(lambda qq, ss: codec.decode(qq, ss))(qg, sg)
     full = jnp.sum(dec, axis=0)[:n0].astype(x.dtype).reshape(shp)
     blk = shp[dim] // n
-    idx = prims.axis_rank(axis_name, ranks)
+    idx = lax.axis_index(axis_name)
     return lax.dynamic_slice_in_dim(full, idx * blk, blk, axis=dim)
 
 
 def compressed_psum_topk(x: jax.Array, axis_name: str, codec: TopKCodec,
-                         ef: Optional[jax.Array] = None,
-                         ranks: prims.Ranks = None
+                         ef: Optional[jax.Array] = None
                          ) -> Tuple[jax.Array, Optional[jax.Array]]:
     if ef is not None:
         x = x + ef
     vals, idx = codec.encode(x)
     n = x.shape[0]
     new_ef = x - codec.decode(vals, idx, n) if ef is not None else None
-    vg = prims.all_gather_stacked(vals, axis_name, ranks)  # (P, k)
-    ig = prims.all_gather_stacked(idx, axis_name, ranks)  # (P, k)
+    vg = lax.all_gather(vals, axis_name)  # (P, k)
+    ig = lax.all_gather(idx, axis_name)  # (P, k)
     out = jnp.zeros((n,), x.dtype).at[ig.reshape(-1)].add(vg.reshape(-1).astype(x.dtype))
     return out, new_ef
 

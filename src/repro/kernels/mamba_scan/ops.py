@@ -1,4 +1,4 @@
-"""Jit'd public wrapper for the mamba selective-scan kernel."""
+"""Public wrapper for the mamba selective-scan kernel."""
 from __future__ import annotations
 
 from typing import Optional, Tuple
@@ -9,15 +9,11 @@ import jax.numpy as jnp
 from repro.kernels.mamba_scan.kernel import mamba_scan_fwd
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def mamba_scan(u, dt, A, Bc, Cc, D, state: Optional[jax.Array] = None
-               ) -> Tuple[jax.Array, jax.Array]:
+def mamba_scan(u, dt, A, Bc, Cc, D, state: Optional[jax.Array] = None, *,
+               interpret: bool = False) -> Tuple[jax.Array, jax.Array]:
     """Model layout (same as kernel). state defaults to zeros."""
     B, S, di = u.shape
     ds = A.shape[1]
     if state is None:
         state = jnp.zeros((B, di, ds), jnp.float32)
-    return mamba_scan_fwd(u, dt, A, Bc, Cc, D, state, interpret=not _on_tpu())
+    return mamba_scan_fwd(u, dt, A, Bc, Cc, D, state, interpret=interpret)

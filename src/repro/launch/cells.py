@@ -100,7 +100,7 @@ def build_cell(arch_name: str, shape_name: str, mesh: Mesh, *,
                moe_groups: int = 1,
                loss_chunk: Optional[int] = None,
                context_parallel: bool = False,
-               embed_tp: Optional[bool] = None) -> Cell:
+               embed_tp: bool = True) -> Cell:
     arch = get_arch(arch_name)
     shape = SHAPES[shape_name]
     ok, why = shape_applicable(arch, shape)

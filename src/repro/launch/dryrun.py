@@ -53,7 +53,7 @@ def run_cell(arch_name: str, shape_name: str, *, multi_pod: bool,
              zero1: bool = True, microbatches: Optional[int] = None,
              seq_shard: bool = False, moe_groups: int = 1,
              loss_chunk: Optional[int] = None, context_parallel: bool = False,
-             embed_tp: Optional[bool] = None,
+             embed_tp: bool = True,
              save_hlo: Optional[str] = None) -> Dict:
     t0 = time.time()
     mesh = make_production_mesh(multi_pod=multi_pod)
@@ -196,7 +196,7 @@ def main() -> None:
                                microbatches=args.microbatches,
                                seq_shard=args.seq_shard,
                                context_parallel=args.context_parallel,
-                               embed_tp=(False if args.no_embed_tp else None),
+                               embed_tp=not args.no_embed_tp,
                                moe_groups=args.moe_groups,
                                loss_chunk=args.loss_chunk,
                                save_hlo=args.save_hlo)
@@ -213,6 +213,8 @@ def main() -> None:
                 gc.collect()
     n_ok = sum(1 for r in results if r.get("ok"))
     print(f"\n{n_ok}/{len(results)} cells OK")
+    if n_ok < len(results):
+        raise SystemExit(f"{len(results) - n_ok} cell(s) failed")
 
 
 if __name__ == "__main__":

@@ -13,6 +13,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import get_arch, get_smoke_arch
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.registry import build_model
 from repro.models.transformer import ModelSettings
 from repro.obs.metrics import MetricsLogger
@@ -32,6 +33,7 @@ def main() -> None:
     ap.add_argument("--metrics-path", default=None,
                     help="streamed JSONL metrics (repro.obs.metrics)")
     args = ap.parse_args()
+    use_compile_cache()
 
     arch = get_smoke_arch(args.arch) if args.smoke else get_arch(args.arch)
     st = ModelSettings(param_dtype="float32", compute_dtype="float32",

@@ -19,6 +19,7 @@ import jax
 
 from repro.configs.base import SHAPES, ShapeConfig, get_arch, get_smoke_arch
 from repro.core.topology import TwoTierTopology
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.registry import build_model
 from repro.models.transformer import ModelSettings
 from repro.runtime.train_loop import Trainer, TrainerConfig
@@ -53,6 +54,7 @@ def main() -> None:
                          "record per step as it happens, unlike the "
                          "post-hoc --metrics-out dump")
     args = ap.parse_args()
+    use_compile_cache()
 
     arch = get_smoke_arch(args.arch) if args.smoke else get_arch(args.arch)
     if args.shape:

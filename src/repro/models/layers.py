@@ -9,7 +9,7 @@ GQA-aware and has three implementations:
   * ``tri``     — static triangular decomposition (recursive halving with
                   online-softmax merge; rectangles carry zero wasted FLOPs)
                   — the beyond-paper optimization logged in EXPERIMENTS §Perf,
-  * ``pallas``  — the flash-attention kernel (TPU target; interpret on CPU).
+  * ``pallas``  — the flash-attention kernel (compiled for the TPU only).
 """
 from __future__ import annotations
 
@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import ArchConfig
-from repro.utils import jax_compat
 
 Params = Dict[str, Any]
 
@@ -159,11 +158,8 @@ def _attn_rect_chunked(q, k, v, *, q_chunk: int, kv_chunk: int, scale: float,
         # qi: (B, q_chunk, KV, G, hd)
         def kv_step(carry, j):
             m, l, o = carry
-            if isinstance(j, int):  # unrolled (partial-manual-safe) path
-                kj, vj = kk[:, j], vv[:, j]
-            else:
-                kj = lax.dynamic_index_in_dim(kk, j, axis=1, keepdims=False)
-                vj = lax.dynamic_index_in_dim(vv, j, axis=1, keepdims=False)
+            kj = lax.dynamic_index_in_dim(kk, j, axis=1, keepdims=False)
+            vj = lax.dynamic_index_in_dim(vv, j, axis=1, keepdims=False)
             s = jnp.einsum("bqkgh,bskh->bkgqs", qi, kj,
                            preferred_element_type=jnp.float32) * scale
             if mask == "causal":
@@ -185,21 +181,11 @@ def _attn_rect_chunked(q, k, v, *, q_chunk: int, kv_chunk: int, scale: float,
         m0 = jnp.full((B, KV, G, q_chunk), -jnp.inf, jnp.float32)
         l0 = jnp.zeros((B, KV, G, q_chunk), jnp.float32)
         o0 = jnp.zeros((B, KV, G, q_chunk, hd), jnp.float32)
-        if jax_compat.HAS_PARTIAL_MANUAL_LOOPS:
-            (m, l, o), _ = lax.scan(kv_step, (m0, l0, o0), jnp.arange(nk))
-        else:
-            carry = (m0, l0, o0)
-            for j in range(nk):
-                carry, _ = kv_step(carry, j)
-            m, l, o = carry
+        (m, l, o), _ = lax.scan(kv_step, (m0, l0, o0), jnp.arange(nk))
         return m, l, o
 
-    if jax_compat.HAS_PARTIAL_MANUAL_LOOPS:
-        ms, ls, os_ = lax.map(lambda args: q_block(args[0], args[1]),
-                              (jnp.moveaxis(qq, 1, 0), jnp.arange(nq)))
-    else:
-        parts = [q_block(qq[:, i], i) for i in range(nq)]
-        ms, ls, os_ = (jnp.stack([p[t] for p in parts]) for t in range(3))
+    ms, ls, os_ = lax.map(lambda args: q_block(args[0], args[1]),
+                          (jnp.moveaxis(qq, 1, 0), jnp.arange(nq)))
     # ms: (nq, B, KV, G, q_chunk) -> (B, KV, G, Sq)
     m = jnp.moveaxis(ms, 0, 3).reshape(B, KV, G, Sq)
     l = jnp.moveaxis(ls, 0, 3).reshape(B, KV, G, Sq)
@@ -644,7 +630,7 @@ def _moe_dispatch(arch: ArchConfig, p: Params, xg: jax.Array,
 
     logits = (xg.astype(jnp.float32) @ p["router"])  # (G, Tl, E)
     probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, topk_idx = jax_compat.top_k(probs, k)  # (G, Tl, k)
+    gate_vals, topk_idx = lax.top_k(probs, k)  # (G, Tl, k)
     gate_vals = gate_vals / jnp.maximum(jnp.sum(gate_vals, -1, keepdims=True), 1e-9)
 
     # load-balance aux loss (Switch-style, averaged over groups)

@@ -26,7 +26,6 @@ from jax import lax
 from repro.configs.base import ArchConfig
 from repro.models import layers as L
 from repro.models import ssm as S
-from repro.utils import jax_compat
 
 Params = Dict[str, Any]
 
@@ -346,14 +345,7 @@ def encode(arch: ArchConfig, params: Params, frames: jax.Array,
     body_fn = body
     if st.remat != "none":
         body_fn = jax.checkpoint(body, policy=_remat_policy(st))
-    if jax_compat.HAS_PARTIAL_MANUAL_LOOPS:
-        x, _ = lax.scan(body_fn, x, params["enc_blocks"])
-    else:
-        # unrolled: scans over auto-axis-sharded params abort the 0.4.x
-        # partitioner under partial-manual shard_map (see jax_compat)
-        n_enc = jax.tree.leaves(params["enc_blocks"])[0].shape[0]
-        for gi in range(n_enc):
-            x, _ = body_fn(x, jax.tree.map(lambda a: a[gi], params["enc_blocks"]))
+    x, _ = lax.scan(body_fn, x, params["enc_blocks"])
     return L.apply_norm(arch, params["enc_final_norm"], x)
 
 
@@ -399,7 +391,7 @@ def forward(arch: ArchConfig, params: Params, tokens: jax.Array,
     body_fn = body
     if st.remat != "none":
         body_fn = jax.checkpoint(body, policy=_remat_policy(st))
-    if st.scan_layers and jax_compat.HAS_PARTIAL_MANUAL_LOOPS:
+    if st.scan_layers:
         (x, aux), caches = lax.scan(body_fn, (x, jnp.zeros((), jnp.float32)),
                                     params["blocks"])
     else:

@@ -41,7 +41,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from repro.core import prims
 from repro.core.collectives import (dfabric_all_gather, dfabric_all_reduce,
                                     dfabric_reduce_scatter, pod_psum)
 from repro.utils.jax_compat import axis_size
@@ -122,12 +121,12 @@ class SyncSettings:
         return self.n_fast * self.n_slow
 
 
-def flat_fast_index(ss: SyncSettings, ranks: prims.Ranks = None):
+def flat_fast_index(ss: SyncSettings):
     """This rank's flattened index over the fast tiers, fastest-tier-major
     (matches the ownership order of ``dfabric_reduce_scatter``)."""
     idx = None
     for a in ss.fast:
-        i = prims.axis_rank(a, ranks)
+        i = lax.axis_index(a)
         idx = i if idx is None else idx * axis_size(a) + i
     return idx if idx is not None else jnp.int32(0)
 
@@ -291,16 +290,13 @@ def merged_state_specs(plan: SyncPlan, param_shapes: Dict[str, Any],
 
 def sync_and_update(params, grads, sync_state, plan: SyncPlan,
                     ss: SyncSettings, lr, opt_cfg: AdamWConfig,
-                    fast_idx=None, ranks: prims.Ranks = None
+                    fast_idx=None
                     ) -> Tuple[Any, Any, Dict[str, jax.Array]]:
     """Execute the plan; returns (new_params, new_sync_state, metrics).
 
     ``fast_idx``: this rank's flattened index over the fast tiers.  Must be
     computed *outside* when running inside the nested model-manual
     shard_map (axis_index of a parent-manual axis is not allowed there).
-    ``ranks``: per-axis rank indices threaded in as data — REQUIRED on the
-    0.4.x stack when a TP axis stays auto, where ``lax.axis_index`` of a
-    manual axis cannot lower (see ``repro.core.prims``).
     """
     pflat = tree_paths(params)
     gflat = tree_paths(grads)
@@ -334,8 +330,7 @@ def sync_and_update(params, grads, sync_state, plan: SyncPlan,
         if zero1_path:
             shard, new_ef = dfabric_reduce_scatter(
                 g, ss.fast, ss.slow_axis, sec.sync, scatter_dim=k, ef=ef,
-                ranks=ranks, schedule=sec.schedule, lane_offset=lane_off,
-                staging=staging)
+                schedule=sec.schedule, lane_offset=lane_off, staging=staging)
             shard = shard * inv_dp
             synced[sec.name] = ("shard", shard, k)
             sqnorm = sqnorm + lax.psum(jnp.sum(jnp.square(shard)),
@@ -343,8 +338,7 @@ def sync_and_update(params, grads, sync_state, plan: SyncPlan,
         else:
             full, new_ef = dfabric_all_reduce(
                 g, ss.fast, ss.slow_axis, sec.sync, scatter_dim=k, ef=ef,
-                ranks=ranks, schedule=sec.schedule, lane_offset=lane_off,
-                staging=staging)
+                schedule=sec.schedule, lane_offset=lane_off, staging=staging)
             full = full * inv_dp
             synced[sec.name] = ("full", full, k)
             sq = jnp.sum(jnp.square(full))
@@ -368,7 +362,7 @@ def sync_and_update(params, grads, sync_state, plan: SyncPlan,
         if kind == "shard":
             # parameter shard owned by this fast-tier rank (flattened
             # fastest-tier-major over all fast axes)
-            idx = fast_idx if fast_idx is not None else flat_fast_index(ss, ranks)
+            idx = fast_idx if fast_idx is not None else flat_fast_index(ss)
             if bucket:
                 p_full = _bucket_pack(pflat, sec, n_fast)
                 blk = p_full.shape[0] // n_fast
@@ -383,8 +377,7 @@ def sync_and_update(params, grads, sync_state, plan: SyncPlan,
             # the all-gather now carries UPDATED PARAMETERS (fused ZeRO-1);
             # gathers run up the fast tiers in reverse scatter order
             gathered = dfabric_all_gather(new_p_sh, ss.fast,
-                                          gather_dim=(0 if bucket else k),
-                                          ranks=ranks)
+                                          gather_dim=(0 if bucket else k))
             if bucket:
                 new_flat.update(_bucket_unpack(gathered, sec, pflat))
             else:

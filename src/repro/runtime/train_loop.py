@@ -27,7 +27,6 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig, ShapeConfig
-from repro.core import prims
 from repro.core.planner import Planner, SyncPlan
 from repro.core.topology import TwoTierTopology, topology_from_mesh_sizes
 from repro.utils import jax_compat
@@ -49,11 +48,6 @@ from repro.utils.trees import tree_paths
 #: "host" is the optional mid tier of a 3-tier fabric (rack-level CXL).
 DP_MESH_AXES = ("pod", "host", "data")
 
-#: hidden batch key carrying each DP member's flat rank as data (needed by
-#: the 0.4.x partitioner, where axis_index cannot lower under
-#: partial-manual shard_map — see repro.core.prims)
-DP_RANK_KEY = "__dp_rank__"
-
 
 def dp_axes_of(sizes) -> Tuple[str, ...]:
     return tuple(a for a in DP_MESH_AXES if a in sizes)
@@ -66,14 +60,8 @@ def fast_axes_of(sizes) -> Tuple[str, ...]:
 
 
 def mesh_info(mesh: Mesh, *, fsdp: bool = False,
-              embed_tp: Optional[bool] = None) -> MeshInfo:
-    if embed_tp is None:
-        # vocab-sharded tables turn the embedding lookup into a gather whose
-        # operand is sharded over the auto (TP) axis; the 0.4.x SPMD
-        # partitioner hard-aborts on such gathers inside a partial-manual
-        # shard_map, so dfabric mode replicates the tables on that stack.
-        # GSPMD (fsdp) mode has no manual region and keeps vocab TP.
-        embed_tp = fsdp or prims.HAS_PARTIAL_MANUAL_COLLECTIVES
+              embed_tp: bool = True) -> MeshInfo:
+    """``embed_tp``: shard the vocab tables over the TP axis."""
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     return MeshInfo(sizes, tp_axis="model" if "model" in sizes else None,
                     fsdp_axis="data" if fsdp else None,
@@ -93,7 +81,7 @@ def batch_sharding(mesh: Mesh, model: Model, mi: MeshInfo):
 def make_sync_plan(model: Model, mesh: Mesh, topo, *,  # topo: TwoTierTopology | FabricSpec
                    codec: Optional[str] = None, strategy: str = "auto",
                    bucket_bytes: int = 4 << 20,
-                   embed_tp: Optional[bool] = None,
+                   embed_tp: bool = True,
                    pipeline: bool = True,
                    mid_codec: Optional[str] = None) -> Tuple[SyncPlan, SyncSettings]:
     mi = mesh_info(mesh, embed_tp=embed_tp)
@@ -135,7 +123,7 @@ def make_dfabric_train_step(model: Model, mesh: Mesh, plan: SyncPlan,
                             ss: SyncSettings, opt_cfg: AdamWConfig,
                             lr_fn: Callable, *, microbatches: int = 1,
                             zero1: bool = True, donate: bool = True,
-                            embed_tp: Optional[bool] = None):
+                            embed_tp: bool = True):
     """Returns (step_fn(params, sync_state, batch, step_idx) ->
     (params, sync_state, metrics), init_sync_state_fn, state_sharding).
 
@@ -143,15 +131,11 @@ def make_dfabric_train_step(model: Model, mesh: Mesh, plan: SyncPlan,
     auto TP; the gradient sync runs inside a NESTED shard_map that also
     manualizes the TP axis — psum_scatter of TP-sharded gradients is then
     a purely local reduce-scatter instead of a full replication gather
-    (§Perf iter. 6).  A hidden ``__dp_rank__`` batch input (an arange
-    sharded over the DP axes) threads each member's rank in as DATA, which
-    the 0.4.x partitioner needs because ``axis_index`` cannot lower under
-    partial-manual shard_map (see ``repro.core.prims``).
+    (§Perf iter. 6).
     """
     if not zero1:
         ss = dataclasses.replace(ss, mode="paper")
     arch = model.arch
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     manual = set(ss.fast) | ({ss.slow_axis} if ss.slow_axis else set())
     dp_axes = tuple(a for a in DP_MESH_AXES if a in manual)
     dp_spec = dp_axes if len(dp_axes) > 1 else dp_axes[0]
@@ -160,23 +144,17 @@ def make_dfabric_train_step(model: Model, mesh: Mesh, plan: SyncPlan,
 
     mi = mesh_info(mesh, embed_tp=embed_tp)
     pspecs_model = model.param_specs(mi)
-    # the nested model-manual shard_map only lowers on the modern
-    # partitioner; older JAX runs the sync with "model" as an auto axis
-    use_nested = ss.model_axis is not None and jax_compat.HAS_NESTED_SHARD_MAP
-    if use_nested:
+    if ss.model_axis is not None:
         in_state_specs = grad_sync.inner_state_specs(
             plan, tree_paths(pspecs_model), tree_paths(pshapes))
-        ss_inner = ss
-    else:
-        ss_inner = dataclasses.replace(ss, model_axis=None)
 
-    def run_sync(params, grads, sync_state, lr, ranks):
-        if not use_nested:
-            return sync_and_update(params, grads, sync_state, plan,
-                                   ss_inner, lr, opt_cfg, ranks=ranks)
-        fast_idx = grad_sync.flat_fast_index(ss, ranks)  # parent-manual axes
+    def run_sync(params, grads, sync_state, lr):
+        if ss.model_axis is None:
+            return sync_and_update(params, grads, sync_state, plan, ss, lr,
+                                   opt_cfg)
+        fast_idx = grad_sync.flat_fast_index(ss)  # parent-manual axes
         inner = jax_compat.shard_map(
-            lambda p, g, s, lr_, fi: sync_and_update(p, g, s, plan, ss_inner,
+            lambda p, g, s, lr_, fi: sync_and_update(p, g, s, plan, ss,
                                                      lr_, opt_cfg, fast_idx=fi),
             in_specs=(pspecs_model, pspecs_model, in_state_specs, P(), P()),
             out_specs=(pspecs_model, in_state_specs, {"grad_norm": P()}),
@@ -184,16 +162,6 @@ def make_dfabric_train_step(model: Model, mesh: Mesh, plan: SyncPlan,
         return inner(params, grads, sync_state, lr, fast_idx)
 
     def step_body(params, sync_state, batch, step_idx):
-        batch = dict(batch)
-        # decompose this member's flat DP rank (slowest-axis-major, the
-        # layout order of P(dp_axes)) into per-axis indices
-        rem = batch.pop(DP_RANK_KEY).reshape(-1)[0]
-        ranks = {}
-        for a in reversed(dp_axes):
-            n = sizes[a]
-            ranks[a] = rem % n
-            rem = rem // n
-
         def loss_of(p, b):
             return model.loss(p, b)
 
@@ -206,15 +174,7 @@ def make_dfabric_train_step(model: Model, mesh: Mesh, plan: SyncPlan,
                                     + a.shape[1:]), batch)
             zero = (jnp.zeros(()),
                     jax.tree.map(lambda p: jnp.zeros(p.shape, p.dtype), params))
-            if jax_compat.HAS_PARTIAL_MANUAL_LOOPS:
-                (loss, grads), _ = lax.scan(micro, zero, mbatch)
-            else:
-                # unrolled: the scan carry holds auto-axis-sharded grads,
-                # which aborts the 0.4.x partitioner here (see jax_compat)
-                acc = zero
-                for i in range(microbatches):
-                    acc, _ = micro(acc, jax.tree.map(lambda a: a[i], mbatch))
-                loss, grads = acc
+            (loss, grads), _ = lax.scan(micro, zero, mbatch)
             loss = loss / microbatches
             grads = jax.tree.map(lambda g: g / microbatches, grads)
         else:
@@ -222,8 +182,7 @@ def make_dfabric_train_step(model: Model, mesh: Mesh, plan: SyncPlan,
 
         loss = lax.pmean(loss, dp_axes if len(dp_axes) > 1 else dp_axes[0])
         lr = lr_fn(step_idx)
-        new_params, new_state, metrics = run_sync(params, grads, sync_state,
-                                                  lr, ranks)
+        new_params, new_state, metrics = run_sync(params, grads, sync_state, lr)
         metrics = dict(metrics)
         metrics["loss"] = loss
         metrics["lr"] = lr * jnp.ones(())
@@ -232,7 +191,6 @@ def make_dfabric_train_step(model: Model, mesh: Mesh, plan: SyncPlan,
     batch_specs = {k: P(dp_spec, *([None] * 1)) for k in ("tokens", "labels")}
     if arch.is_encdec:
         batch_specs["frames"] = P(dp_spec, None, None)
-    batch_specs[DP_RANK_KEY] = P(dp_spec)
     metric_specs = {"loss": P(), "grad_norm": P(), "lr": P()}
 
     fn = jax_compat.shard_map(step_body, mesh=mesh,
@@ -240,22 +198,7 @@ def make_dfabric_train_step(model: Model, mesh: Mesh, plan: SyncPlan,
                               out_specs=(P(), state_specs, metric_specs),
                               axis_names=manual, check_vma=False)
     jit_kw = dict(donate_argnums=(0, 1)) if donate else {}
-    jit_fn = jax.jit(fn, **jit_kw)
-    # device-resident once: feeding a host array would re-transfer and
-    # reshard the rank vector on every step
-    rank_arr = jax.device_put(
-        np.arange(max(ss.dp_total, 1), dtype=np.int32),
-        NamedSharding(mesh, P(dp_spec)))
-
-    def step_fn(params, sync_state, batch, step_idx):
-        return jit_fn(params, sync_state, {**batch, DP_RANK_KEY: rank_arr},
-                      step_idx)
-
-    def _lower(params, sync_state, batch, step_idx):
-        return jit_fn.lower(params, sync_state,
-                            {**batch, DP_RANK_KEY: rank_arr}, step_idx)
-
-    step_fn.lower = _lower  # keep the .lower() contract of a jitted callable
+    step_fn = jax.jit(fn, **jit_kw)
 
     def init_state():
         return grad_sync.init_sync_state(plan, pshapes, ss)
@@ -545,8 +488,11 @@ class Trainer:
         finally:
             # emit the final 'summary' record and release the JSONL handle
             self.metrics.close()
-        if self.ckpt:
-            self.ckpt.wait()
+            # drain the async checkpoint write even when a step raised: a
+            # writer still running would race whoever restarts in ckpt_dir
+            # (its new manager sweeps the half-written .tmp-step dir)
+            if self.ckpt:
+                self.ckpt.wait()
         return {"params": params, "opt": opt, "step": step,
                 "metrics": self.metrics_log,
                 "straggler_events": self.watchdog.events}

@@ -1,150 +1,43 @@
-"""Version shims for JAX API drift.
+"""Thin mesh / shard_map helpers shared by the runtime, tests and examples.
 
-The repo targets two generations of JAX:
-
-  * modern (>= 0.6): ``jax.shard_map(..., axis_names=..., check_vma=...)``,
-    ``jax.sharding.AxisType``, ``jax.make_mesh(..., axis_types=...)``,
-    ``lax.axis_size`` and ``pltpu.CompilerParams``;
-  * 0.4.x (the pinned CI/toolchain image): ``jax.experimental.shard_map``
-    with ``check_rep``/``auto``, no ``AxisType``, no ``axis_types=`` kwarg,
-    no ``lax.axis_size`` and ``pltpu.TPUCompilerParams``.
-
-Everything that touches one of those APIs goes through this module so the
-rest of the codebase is version-agnostic.  Capability flags (``HAS_*``)
-let call sites gate features that only exist on one side (e.g. nested
-shard_map, which the 0.4.x SPMD partitioner rejects).
+``make_mesh`` builds a mesh whose axes are all ``AxisType.Auto`` (JAX's own
+``make_mesh`` now defaults to ``Explicit``, which the GSPMD steps do not
+use); ``shard_map`` names the manual axes and turns the VMA check off by
+default; ``axis_size`` reports 1 for an axis that is None or unbound, so
+collectives written for a tier degrade to no-ops on a mesh without it.
 """
 from __future__ import annotations
 
 from typing import Optional, Sequence
 
 import jax
-import jax.numpy as jnp
 from jax import lax
-
-# ---------------------------------------------------------------------------
-# capability probes
-# ---------------------------------------------------------------------------
-
-try:
-    from jax.sharding import AxisType as _AxisType  # type: ignore[attr-defined]
-    HAS_AXIS_TYPE = True
-except ImportError:
-    _AxisType = None
-    HAS_AXIS_TYPE = False
-
-HAS_NEW_SHARD_MAP = hasattr(jax, "shard_map")
-if not HAS_NEW_SHARD_MAP:
-    from jax.experimental.shard_map import shard_map as _old_shard_map
-
-# nested shard_map (manualizing a leftover auto axis inside a manual
-# region) only lowers correctly on the modern partitioner
-HAS_NESTED_SHARD_MAP = HAS_NEW_SHARD_MAP
-
-# while-loops (lax.scan / lax.map) whose operands are sharded over an AUTO
-# axis hard-abort the 0.4.x SPMD partitioner inside a partial-manual
-# shard_map (hlo_sharding_util: `Check failed: sharding.IsManualSubgroup()`);
-# statically unrolled indexing lowers fine.  Code that may run in that
-# regime gates its scans on this flag.
-HAS_PARTIAL_MANUAL_LOOPS = HAS_NEW_SHARD_MAP
-
-HAS_LAX_AXIS_SIZE = hasattr(lax, "axis_size")
-
-
-# ---------------------------------------------------------------------------
-# mesh construction
-# ---------------------------------------------------------------------------
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices: Optional[Sequence] = None):
-    """``jax.make_mesh`` with all axes Auto, on any JAX version."""
-    kw = {"devices": devices} if devices is not None else {}
-    if HAS_AXIS_TYPE:
-        try:
-            return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                                 axis_types=(_AxisType.Auto,) * len(tuple(axis_shapes)),
-                                 **kw)
-        except TypeError:  # make_mesh predates axis_types
-            pass
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kw)
-
-
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
+    """``jax.make_mesh`` with all axes Auto."""
+    shape = tuple(axis_shapes)
+    return jax.make_mesh(shape, tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def shard_map(f, *, mesh=None, in_specs, out_specs, axis_names=None,
               check_vma: bool = False):
-    """Cross-version ``shard_map``.
-
-    ``axis_names``: the MANUAL axes (None = all mesh axes).  On 0.4.x this
-    is translated to the complementary ``auto`` set, which requires ``mesh``
-    to be passed explicitly.
-    """
-    if HAS_NEW_SHARD_MAP:
-        kw = {}
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma, **kw)
-    if mesh is None:
-        raise ValueError("jax<0.6 shard_map requires an explicit mesh")
-    auto = (frozenset(mesh.axis_names) - frozenset(axis_names)
-            if axis_names is not None else frozenset())
-    return _old_shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_rep=check_vma, auto=auto)
-
-
-# ---------------------------------------------------------------------------
-# axis queries (inside shard_map)
-# ---------------------------------------------------------------------------
+    """``jax.shard_map``; ``axis_names`` are the MANUAL axes (None = all
+    mesh axes)."""
+    kw = {"axis_names": set(axis_names)} if axis_names is not None else {}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kw)
 
 
 def axis_size(axis_name) -> int:
     """Static size of a bound manual axis; 1 for None/unbound names."""
     if axis_name is None:
         return 1
-    if HAS_LAX_AXIS_SIZE:
-        try:
-            return lax.axis_size(axis_name)
-        except NameError:
-            return 1
     try:
-        # psum of a python scalar constant-folds to the axis size
-        return lax.psum(1, axis_name)
+        return lax.axis_size(axis_name)
     except NameError:
         return 1
-
-
-# ---------------------------------------------------------------------------
-# partial-manual-safe ops
-# ---------------------------------------------------------------------------
-
-
-def top_k(x, k: int):
-    """``lax.top_k`` on the modern stack; argsort-based on 0.4.x, where
-    the TopK lowering hard-aborts the SPMD partitioner inside a
-    partial-manual shard_map (plain variadic sort lowers fine there).
-    Matches ``lax.top_k`` ordering: values descending, ties broken by
-    lowest index (stable argsort of the negated input)."""
-    if HAS_PARTIAL_MANUAL_LOOPS:
-        return lax.top_k(x, k)
-    idx = jnp.argsort(-x, axis=-1)[..., :k].astype(jnp.int32)
-    vals = jnp.take_along_axis(x, idx, axis=-1)
-    return vals, idx
-
-
-# ---------------------------------------------------------------------------
-# compiled-artifact introspection
-# ---------------------------------------------------------------------------
-
-
-def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict on any JAX version
-    (0.4.x returns a one-element list of per-device dicts)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)

@@ -21,6 +21,7 @@ build / price / lower / simulate contract:
 """
 import os
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import itertools
 import json

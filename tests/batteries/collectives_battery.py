@@ -9,6 +9,7 @@ Asserts, on a (2 pods x 2 data x 2 model) mesh:
 """
 import os
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax
 import jax.numpy as jnp
@@ -89,9 +90,7 @@ for mode in ("zero1", "paper"):
         g = jax.tree.map(lambda a: a[0], g)  # strip the member dim
         np_, ns, m = sync_and_update(p, g, s, plan, ss, 1e-2, opt_cfg)
         return np_
-    # NOTE: all mesh axes manual ("model" is unused but manualizing it keeps
-    # the 0.4.x partitioner happy — partial-manual all_gather/axis_index
-    # don't lower there; the real train step threads ranks in as data)
+    # all mesh axes manual ("model" is unused here)
     f = jax.jit(jax_compat.shard_map(
         step, mesh=mesh,
         in_specs=(P(), specs,

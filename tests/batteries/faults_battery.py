@@ -8,6 +8,7 @@ then kills most of the rack pool mid-fleet and asserts replanned
 schedules (prefill rerouted onto the CXL shortcut) claw back goodput."""
 import os
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import tempfile
 

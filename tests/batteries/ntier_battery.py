@@ -11,6 +11,7 @@ at every depth, on 1-, 2- and 3-tier DP meshes over the same 8 members:
 """
 import os
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax
 import jax.numpy as jnp

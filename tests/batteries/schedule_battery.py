@@ -21,6 +21,7 @@ The overlapped-executor acceptance battery:
 """
 import os
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 from dataclasses import replace
 

@@ -3,6 +3,7 @@ a few steps in every mode and assert the loss decreases; lower a small
 dry-run cell to validate the launch path end-to-end."""
 import os
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax
 import jax.numpy as jnp

@@ -18,6 +18,9 @@ def run_multi_device(script_path: str, n_devices: int = 8, timeout: int = 600,
                      extra_env=None):
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    # the child must never reach for an accelerator: on a TPU host its
+    # parent (the pytest worker) may already hold the chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     if extra_env:
         env.update(extra_env)

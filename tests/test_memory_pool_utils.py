@@ -84,7 +84,7 @@ def test_offload_sharding_falls_back_without_pinned_host():
     plain = offload_sharding(mesh, P(), offload=False)
     assert isinstance(plain, NamedSharding)
     offloaded = offload_sharding(mesh, P(), offload=True)
-    # the CPU backend here has no pinned_host memory kind: the offload
+    # on a backend without the pinned_host memory kind the offload
     # request must degrade to the plain device sharding, not raise
     if not host_memory_kind_available():
         assert offloaded.memory_kind == plain.memory_kind

@@ -8,7 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import run_multi_device
+from conftest import REPO, run_multi_device
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -161,8 +161,7 @@ def test_analytics_matches_xla_costs(name):
     params = m.init(jax.random.key(0))
     c = jax.jit(lambda p, t: m.prefill(p, t)[0]).lower(
         params, jnp.zeros((4, 64), jnp.int32)).compile()
-    from repro.utils.jax_compat import cost_analysis
-    hlo_flops = cost_analysis(c)["flops"]
+    hlo_flops = c.cost_analysis()["flops"]
     est = model_cost(m, shape, "prefill")["fwd_flops"]
     assert 0.85 < est / hlo_flops < 1.15, (est, hlo_flops)
 
@@ -215,3 +214,22 @@ def test_decode_server_continuous_batching():
     assert len(outs) == 5
     assert all(len(toks) == 4 for toks in outs.values())
     assert server.throughput() > 0
+
+
+def test_dryrun_exits_nonzero_when_a_cell_fails(tmp_path):
+    """A failed cell is recorded with ``ok: False`` AND fails the run."""
+    import subprocess
+    import sys
+    code = ("import sys\n"
+            "import repro.launch.dryrun as d\n"
+            "d.run_cell = lambda *a, **k: {'ok': False, 'error': 'boom'}\n"
+            "sys.argv = ['dryrun', '--arch', 'qwen2-0.5b', '--shape',\n"
+            "            'train_4k', '--mesh', 'single', '--out', sys.argv[1]]\n"
+            "d.main()\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(REPO, "src"))
+    proc = subprocess.run([sys.executable, "-c", code, str(tmp_path)],
+                          env=env, capture_output=True, text=True, timeout=300)
+    assert proc.returncode != 0, proc.stdout
+    assert "1 cell(s) failed" in proc.stderr
+    assert list(tmp_path.glob("*.json"))

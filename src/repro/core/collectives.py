@@ -31,6 +31,11 @@ entry points (``dfabric_all_reduce`` / ``dfabric_reduce_scatter``, and
 dispatch traffic) survive as thin constructors: given no schedule they
 build one in-trace from ``(axes, SyncConfig, shape)`` via the same builder
 the planner uses.
+
+Each leg lowers inside a ``jax.named_scope`` named for its kind
+(``reduce_scatter``, ``psum``, ``slow_chunk``, ``all_gather``,
+``all_to_all``): the compiled ops carry it in their ``op_name``, so a
+device trace gives each tier's legs their own time.
 """
 from __future__ import annotations
 
@@ -130,39 +135,43 @@ def _slow_chunk_psum(leg: SlowChunk, x_flat: jax.Array,
                      ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """Lower ONE slow-tier sub-flow (this is the only leg kind where the
     Section codec runs)."""
-    if leg.codec is None:
-        return lax.psum(x_flat, leg.axis), ef_flat
-    assert leg.codec == cfg.codec, (leg.codec, cfg.codec)
-    codec = cfg.make_codec()
-    if isinstance(codec, comp.Int8Codec):
-        return comp.compressed_psum_int8(x_flat, leg.axis, codec, ef_flat)
-    if isinstance(codec, comp.TopKCodec):
-        return comp.compressed_psum_topk(x_flat, leg.axis, codec, ef_flat)
-    raise ValueError(leg.codec)
+    with jax.named_scope("slow_chunk"):
+        if leg.codec is None:
+            return lax.psum(x_flat, leg.axis), ef_flat
+        assert leg.codec == cfg.codec, (leg.codec, cfg.codec)
+        codec = cfg.make_codec()
+        if isinstance(codec, comp.Int8Codec):
+            return comp.compressed_psum_int8(x_flat, leg.axis, codec, ef_flat)
+        if isinstance(codec, comp.TopKCodec):
+            return comp.compressed_psum_topk(x_flat, leg.axis, codec, ef_flat)
+        raise ValueError(leg.codec)
 
 
 def _psum_leg(leg: Psum, x: jax.Array, cfg: SyncConfig) -> jax.Array:
     """Lower one unscattered (mid-tier / flat) psum leg."""
-    if leg.codec is None:
-        return lax.psum(x, leg.axis)
-    # mid-tier codec: int8 without error feedback (EF state belongs to the
-    # slow leg; mid tiers trade exactness for bandwidth per the plan)
-    assert leg.codec == cfg.mid_codec, (leg.codec, cfg.mid_codec)
-    shp = x.shape
-    out, _ = comp.compressed_psum_int8(x.reshape(-1), leg.axis,
-                                       cfg.make_mid_codec(), None)
-    return out.reshape(shp)
+    with jax.named_scope("psum"):
+        if leg.codec is None:
+            return lax.psum(x, leg.axis)
+        # mid-tier codec: int8 without error feedback (EF state belongs to
+        # the slow leg; mid tiers trade exactness for bandwidth per the plan)
+        assert leg.codec == cfg.mid_codec, (leg.codec, cfg.mid_codec)
+        shp = x.shape
+        out, _ = comp.compressed_psum_int8(x.reshape(-1), leg.axis,
+                                           cfg.make_mid_codec(), None)
+        return out.reshape(shp)
 
 
 def _rs_leg(leg: ReduceScatter, x: jax.Array, dim: int,
             cfg: SyncConfig) -> jax.Array:
     """Lower one fast-tier reduce-scatter leg (scattered mid-tier legs may
     carry the mid codec — int8 without error feedback, like mid psums)."""
-    if leg.codec is None:
-        return lax.psum_scatter(x, leg.axis, scatter_dimension=dim, tiled=True)
-    assert leg.codec == cfg.mid_codec, (leg.codec, cfg.mid_codec)
-    return comp.compressed_reduce_scatter_int8(x, leg.axis,
-                                               cfg.make_mid_codec(), dim)
+    with jax.named_scope("reduce_scatter"):
+        if leg.codec is None:
+            return lax.psum_scatter(x, leg.axis, scatter_dimension=dim,
+                                    tiled=True)
+        assert leg.codec == cfg.mid_codec, (leg.codec, cfg.mid_codec)
+        return comp.compressed_reduce_scatter_int8(x, leg.axis,
+                                                   cfg.make_mid_codec(), dim)
 
 
 def _slow_group(legs: Sequence[SlowChunk], x: jax.Array,
@@ -205,7 +214,8 @@ def _apply_down(legs: Sequence, x: jax.Array, dim: int, cfg: SyncConfig,
     def flush():
         nonlocal x
         if pend:
-            x = lax.psum(x, tuple(l.axis for l in pend))
+            with jax.named_scope("psum"):
+                x = lax.psum(x, tuple(l.axis for l in pend))
             if log is not None:
                 log.extend(pend)
             pend.clear()
@@ -241,7 +251,8 @@ def _lower_sequential(schedule: CommSchedule, x: jax.Array,
             log.extend(slow)
     if gather_up:
         for leg in schedule.up_legs:
-            x = lax.all_gather(x, leg.axis, axis=dim, tiled=True)
+            with jax.named_scope("all_gather"):
+                x = lax.all_gather(x, leg.axis, axis=dim, tiled=True)
             if log is not None:
                 log.append(leg)
     return x, ef
@@ -303,7 +314,8 @@ def _lower_pipelined(schedule: CommSchedule, x: jax.Array,
     def gather(buf: jax.Array, lg) -> jax.Array:
         y = buf.reshape(shard_shape)
         for leg in up:
-            y = lax.all_gather(y, leg.axis, axis=dim, tiled=True)
+            with jax.named_scope("all_gather"):
+                y = lax.all_gather(y, leg.axis, axis=dim, tiled=True)
             if lg is not None:
                 lg.append(leg)
         return y
@@ -448,7 +460,8 @@ def dfabric_all_gather(x: jax.Array, fast_axis: Axes,
     fast = normalize_axes(fast_axis)
     for a in reversed(fast):
         if axis_size(a) > 1:
-            x = lax.all_gather(x, a, axis=gather_dim, tiled=True)
+            with jax.named_scope("all_gather"):
+                x = lax.all_gather(x, a, axis=gather_dim, tiled=True)
     return x
 
 
@@ -503,8 +516,9 @@ def lower_all_to_all(schedule: CommSchedule, x: jax.Array,
     k = len(active)
     for i, leg in enumerate(fast_legs):  # fastest tier first
         d = k - 1 - i  # its sub-index dim in the slow-major view
-        y = lax.all_to_all(y, leg.axis, split_axis=d, concat_axis=d,
-                           tiled=True)
+        with jax.named_scope("all_to_all"):
+            y = lax.all_to_all(y, leg.axis, split_axis=d, concat_axis=d,
+                               tiled=True)
         if leg_log is not None:
             leg_log.append(leg)
     if slow:
@@ -517,8 +531,9 @@ def lower_all_to_all(schedule: CommSchedule, x: jax.Array,
         for leg in slow:  # ISSUE order; payload slice picked by index
             part = lax.slice_in_dim(yf, leg.index * blk,
                                     (leg.index + 1) * blk, axis=1)
-            outs[leg.index] = lax.all_to_all(part, leg.axis, split_axis=0,
-                                             concat_axis=0, tiled=True)
+            with jax.named_scope("slow_chunk"):
+                outs[leg.index] = lax.all_to_all(part, leg.axis, split_axis=0,
+                                                 concat_axis=0, tiled=True)
             if leg_log is not None:
                 leg_log.append(leg)
         yf = jnp.concatenate(outs, axis=1) if C > 1 else outs[0]

@@ -113,4 +113,5 @@ def flash_attention_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

@@ -111,5 +111,6 @@ def mamba_scan_fwd(u, dt, A, Bc, Cc, D, h0, *, chunk: int = DEFAULT_CHUNK,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="mamba_scan",
     )(u, dt, A.T, Bc, Cc, D.reshape(1, di), jnp.swapaxes(h0, 1, 2))
     return y, jnp.swapaxes(hT, 1, 2)

@@ -143,5 +143,6 @@ def wkv6_fwd(r, k, v, w, u, s0, *, chunk: int = DEFAULT_CHUNK,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="wkv6",
     )(r, k, v, w, u.reshape(H, 1, hd), s0)
     return y, sT

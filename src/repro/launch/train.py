@@ -26,6 +26,14 @@ from repro.runtime.train_loop import Trainer, TrainerConfig
 from repro.utils.jax_compat import make_mesh
 
 
+def step_range(text: str):
+    """``A:B`` -> (A, B), steps A to B-1."""
+    lo, sep, hi = text.partition(":")
+    if not (sep and lo.isdigit() and hi.isdigit() and int(lo) < int(hi)):
+        raise argparse.ArgumentTypeError(f"{text!r} is not A:B with A < B")
+    return int(lo), int(hi)
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
@@ -53,6 +61,12 @@ def main() -> None:
                     help="streamed JSONL metrics (repro.obs.metrics): one "
                          "record per step as it happens, unlike the "
                          "post-hoc --metrics-out dump")
+    ap.add_argument("--profile-dir", default=None,
+                    help="write a jax.profiler trace (TensorBoard / Perfetto) "
+                         "of --profile-steps here")
+    ap.add_argument("--profile-steps", default=None, metavar="A:B",
+                    type=step_range,
+                    help="trace steps A to B-1 (default: every step)")
     args = ap.parse_args()
     use_compile_cache()
 
@@ -84,7 +98,9 @@ def main() -> None:
                         codec=args.codec, pipeline=not args.no_pipeline,
                         microbatches=args.microbatches,
                         ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,
-                        metrics_path=args.metrics_path)
+                        metrics_path=args.metrics_path,
+                        profile_dir=args.profile_dir,
+                        profile_steps=args.profile_steps)
     trainer = Trainer(model, mesh, shape, cfg)
     trainer.install_preemption_handler()
     out = trainer.train()

@@ -150,103 +150,108 @@ def _apply_layer(arch: ArchConfig, p: Params, x: jax.Array, positions, mode: str
     aux = jnp.zeros((), jnp.float32)
     new_cache: Optional[Params] = None
 
-    h = L.apply_norm(arch, p["ln1"], x)
-    if kind == "attn":
-        # Megatron-SP gather point: attention consumes the full sequence
-        # (replicated over TP); the residual stream stays sequence-sharded.
-        fs = st.full_seq_spec()
-        if fs is not None and mode == "train":
-            h = lax.with_sharding_constraint(h, fs)
-        q, k, v = L.attention_qkv(arch, p["attn"], h, positions)
-        if mode == "decode":
-            kc = lax.dynamic_update_slice_in_dim(cache["k"], k.astype(cache["k"].dtype), pos_scalar, axis=1)
-            vc = lax.dynamic_update_slice_in_dim(cache["v"], v.astype(cache["v"].dtype), pos_scalar, axis=1)
-            lens = jnp.full((x.shape[0],), pos_scalar + 1, jnp.int32)
-            o = L.attend_decode(q, kc, vc, lens)
-            new_cache = {"k": kc, "v": vc}
-        else:
-            o = L.attend(q, k, v, causal=True, impl=st.attn_impl,
-                         block=st.attn_block, q_chunk=st.attn_chunk,
-                         kv_chunk=st.attn_chunk, gqa_repeat=st.gqa_repeat)
-            if mode == "prefill":
-                new_cache = {"k": k, "v": v}
-        attn_out = L.attention_out(p["attn"], o)
-        sp = st.act_spec()
-        if sp is not None and mode == "train":
-            # SP scatter point: the psum of the out-projection becomes a
-            # reduce-scatter back onto the sequence-sharded residual.
-            attn_out = lax.with_sharding_constraint(attn_out, sp)
-        x = x + attn_out
-    elif kind == "mamba":
-        conv_s = cache.get("conv") if cache else None
-        ssm_s = cache.get("ssm") if cache else None
-        out, (ncs, nss) = S.apply_mamba(arch, p["mamba"], h, conv_state=conv_s,
-                                        ssm_state=ssm_s, use_pallas=st.use_pallas_ssm)
-        if mode in ("prefill", "decode"):
-            new_cache = {"conv": ncs, "ssm": nss}
-        x = x + out
-    elif kind == "rwkv":
-        shift_s = cache.get("tshift") if cache else None
-        wkv_s = cache.get("wkv") if cache else None
-        out, (nshift, nwkv) = S.apply_rwkv_time_mix(
-            arch, p["tmix"], h, shift_state=shift_s, wkv_state=wkv_s,
-            use_pallas=st.use_pallas_ssm)
-        if mode in ("prefill", "decode"):
-            new_cache = {"tshift": nshift, "wkv": nwkv}
-        x = x + out
+    # device-time scopes (op_name metadata, no runtime cost): the token
+    # mixer from ln1 to its residual add, then the feed-forward from ln2
+    with jax.named_scope("attention" if kind == "attn" else "ssm"):
+        h = L.apply_norm(arch, p["ln1"], x)
+        if kind == "attn":
+            # Megatron-SP gather point: attention consumes the full sequence
+            # (replicated over TP); the residual stream stays sequence-sharded.
+            fs = st.full_seq_spec()
+            if fs is not None and mode == "train":
+                h = lax.with_sharding_constraint(h, fs)
+            q, k, v = L.attention_qkv(arch, p["attn"], h, positions)
+            if mode == "decode":
+                kc = lax.dynamic_update_slice_in_dim(cache["k"], k.astype(cache["k"].dtype), pos_scalar, axis=1)
+                vc = lax.dynamic_update_slice_in_dim(cache["v"], v.astype(cache["v"].dtype), pos_scalar, axis=1)
+                lens = jnp.full((x.shape[0],), pos_scalar + 1, jnp.int32)
+                o = L.attend_decode(q, kc, vc, lens)
+                new_cache = {"k": kc, "v": vc}
+            else:
+                o = L.attend(q, k, v, causal=True, impl=st.attn_impl,
+                             block=st.attn_block, q_chunk=st.attn_chunk,
+                             kv_chunk=st.attn_chunk, gqa_repeat=st.gqa_repeat)
+                if mode == "prefill":
+                    new_cache = {"k": k, "v": v}
+            attn_out = L.attention_out(p["attn"], o)
+            sp = st.act_spec()
+            if sp is not None and mode == "train":
+                # SP scatter point: the psum of the out-projection becomes a
+                # reduce-scatter back onto the sequence-sharded residual.
+                attn_out = lax.with_sharding_constraint(attn_out, sp)
+            x = x + attn_out
+        elif kind == "mamba":
+            conv_s = cache.get("conv") if cache else None
+            ssm_s = cache.get("ssm") if cache else None
+            out, (ncs, nss) = S.apply_mamba(arch, p["mamba"], h, conv_state=conv_s,
+                                            ssm_state=ssm_s, use_pallas=st.use_pallas_ssm)
+            if mode in ("prefill", "decode"):
+                new_cache = {"conv": ncs, "ssm": nss}
+            x = x + out
+        elif kind == "rwkv":
+            shift_s = cache.get("tshift") if cache else None
+            wkv_s = cache.get("wkv") if cache else None
+            out, (nshift, nwkv) = S.apply_rwkv_time_mix(
+                arch, p["tmix"], h, shift_state=shift_s, wkv_state=wkv_s,
+                use_pallas=st.use_pallas_ssm)
+            if mode in ("prefill", "decode"):
+                new_cache = {"tshift": nshift, "wkv": nwkv}
+            x = x + out
 
     # cross attention (whisper decoder)
-    if "xattn" in p:
-        h = L.apply_norm(arch, p["lnx"], x)
-        q = jnp.einsum("bsd,dhk->bshk", h, p["xattn"]["wq"])
-        if "bq" in p["xattn"]:
-            q = q + p["xattn"]["bq"]
-        if mode == "decode":
-            kx, vx = cache["xk"], cache["xv"]
-        else:
-            eo = enc_out
-            kx = jnp.einsum("bfd,dhk->bfhk", eo, p["xattn"]["wk"])
-            vx = jnp.einsum("bfd,dhk->bfhk", eo, p["xattn"]["wv"])
-            if "bk" in p["xattn"]:
-                kx = kx + p["xattn"]["bk"]
-                vx = vx + p["xattn"]["bv"]
-        o = L.attend(q, kx, vx, causal=False, impl="masked",
-                     q_chunk=st.attn_chunk, kv_chunk=st.attn_chunk)
-        x = x + L.attention_out(p["xattn"], o)
-        if mode in ("prefill", "decode"):
-            new_cache = dict(new_cache or {})
-            new_cache["xk"], new_cache["xv"] = kx, vx
+    with jax.named_scope("attention"):
+        if "xattn" in p:
+            h = L.apply_norm(arch, p["lnx"], x)
+            q = jnp.einsum("bsd,dhk->bshk", h, p["xattn"]["wq"])
+            if "bq" in p["xattn"]:
+                q = q + p["xattn"]["bq"]
+            if mode == "decode":
+                kx, vx = cache["xk"], cache["xv"]
+            else:
+                eo = enc_out
+                kx = jnp.einsum("bfd,dhk->bfhk", eo, p["xattn"]["wk"])
+                vx = jnp.einsum("bfd,dhk->bfhk", eo, p["xattn"]["wv"])
+                if "bk" in p["xattn"]:
+                    kx = kx + p["xattn"]["bk"]
+                    vx = vx + p["xattn"]["bv"]
+            o = L.attend(q, kx, vx, causal=False, impl="masked",
+                         q_chunk=st.attn_chunk, kv_chunk=st.attn_chunk)
+            x = x + L.attention_out(p["xattn"], o)
+            if mode in ("prefill", "decode"):
+                new_cache = dict(new_cache or {})
+                new_cache["xk"], new_cache["xv"] = kx, vx
 
     # feed-forward
-    h = L.apply_norm(arch, p["ln2"], x)
-    sp = st.act_spec()
+    with jax.named_scope("mlp"):
+        h = L.apply_norm(arch, p["ln2"], x)
+        sp = st.act_spec()
 
-    def scatter(out):
-        # SP scatter point: the TP psum of the FF down-projection lowers to
-        # a reduce-scatter onto the sequence-sharded residual
-        if sp is not None and mode == "train":
-            return lax.with_sharding_constraint(out, sp)
-        return out
+        def scatter(out):
+            # SP scatter point: the TP psum of the FF down-projection lowers to
+            # a reduce-scatter onto the sequence-sharded residual
+            if sp is not None and mode == "train":
+                return lax.with_sharding_constraint(out, sp)
+            return out
 
-    if "cmix" in p:
-        shift_s = cache.get("cshift") if cache else None
-        out, nshift = S.apply_rwkv_channel_mix(arch, p["cmix"], h, shift_state=shift_s)
-        if mode in ("prefill", "decode"):
-            new_cache = dict(new_cache or {})
-            new_cache["cshift"] = nshift
-        x = x + scatter(out)
-    elif "moe" in p:
-        dsp = None
-        if st.moe_dispatch_dp or st.moe_dispatch_tp:
-            dp = st.moe_dispatch_dp
-            dp = dp if not (isinstance(dp, tuple) and len(dp) == 1) else dp[0]
-            dsp = (dp, st.moe_dispatch_tp)
-        out, moe_aux = L.apply_moe(arch, p["moe"], h, groups=st.moe_groups,
-                                   dispatch_spec=dsp)
-        aux = aux + moe_aux
-        x = x + scatter(out)
-    else:
-        x = x + scatter(L.apply_mlp(arch, p["mlp"], h))
+        if "cmix" in p:
+            shift_s = cache.get("cshift") if cache else None
+            out, nshift = S.apply_rwkv_channel_mix(arch, p["cmix"], h, shift_state=shift_s)
+            if mode in ("prefill", "decode"):
+                new_cache = dict(new_cache or {})
+                new_cache["cshift"] = nshift
+            x = x + scatter(out)
+        elif "moe" in p:
+            dsp = None
+            if st.moe_dispatch_dp or st.moe_dispatch_tp:
+                dp = st.moe_dispatch_dp
+                dp = dp if not (isinstance(dp, tuple) and len(dp) == 1) else dp[0]
+                dsp = (dp, st.moe_dispatch_tp)
+            out, moe_aux = L.apply_moe(arch, p["moe"], h, groups=st.moe_groups,
+                                       dispatch_spec=dsp)
+            aux = aux + moe_aux
+            x = x + scatter(out)
+        else:
+            x = x + scatter(L.apply_mlp(arch, p["mlp"], h))
     return x, aux, new_cache
 
 
@@ -423,26 +428,27 @@ def logits_from_hidden(arch: ArchConfig, params: Params, x: jax.Array) -> jax.Ar
 
 def ce_loss_chunked(arch: ArchConfig, params: Params, hidden: jax.Array,
                     labels: jax.Array, st: ModelSettings) -> jax.Array:
-    B, Sq, d = hidden.shape
-    chunk = min(st.loss_chunk, Sq)
-    assert Sq % chunk == 0
-    nch = Sq // chunk
-    head = params["embed"].T if arch.tie_embeddings else params["lm_head"]
-    h = hidden.reshape(B, nch, chunk, d).swapaxes(0, 1)  # (nch, B, chunk, d)
-    y = labels.reshape(B, nch, chunk).swapaxes(0, 1)
+    with jax.named_scope("lm_loss"):  # LM head, logsumexp, gold gather
+        B, Sq, d = hidden.shape
+        chunk = min(st.loss_chunk, Sq)
+        assert Sq % chunk == 0
+        nch = Sq // chunk
+        head = params["embed"].T if arch.tie_embeddings else params["lm_head"]
+        h = hidden.reshape(B, nch, chunk, d).swapaxes(0, 1)  # (nch, B, chunk, d)
+        y = labels.reshape(B, nch, chunk).swapaxes(0, 1)
 
-    @jax.checkpoint  # logits are recomputed in bwd — never stored per chunk
-    def body(acc, hy):
-        hc, yc = hy
-        logits = (hc @ head.astype(hc.dtype)).astype(jnp.float32)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, yc[..., None].clip(0), axis=-1)[..., 0]
-        valid = (yc >= 0).astype(jnp.float32)
-        nll = (lse - gold) * valid
-        return (acc[0] + nll.sum(), acc[1] + valid.sum()), None
+        @jax.checkpoint  # logits are recomputed in bwd — never stored per chunk
+        def body(acc, hy):
+            hc, yc = hy
+            logits = (hc @ head.astype(hc.dtype)).astype(jnp.float32)
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            gold = jnp.take_along_axis(logits, yc[..., None].clip(0), axis=-1)[..., 0]
+            valid = (yc >= 0).astype(jnp.float32)
+            nll = (lse - gold) * valid
+            return (acc[0] + nll.sum(), acc[1] + valid.sum()), None
 
-    (tot, cnt), _ = lax.scan(body, (jnp.zeros(()), jnp.zeros(())), (h, y))
-    return tot / jnp.maximum(cnt, 1.0)
+        (tot, cnt), _ = lax.scan(body, (jnp.zeros(()), jnp.zeros(())), (h, y))
+        return tot / jnp.maximum(cnt, 1.0)
 
 
 def train_loss(arch: ArchConfig, params: Params, batch: Dict[str, jax.Array],

@@ -25,15 +25,16 @@ def init_moments(params) -> Dict[str, Any]:
 
 def adamw_leaf(p, g, m, v, step, lr, cfg: AdamWConfig, clip_coef=1.0):
     """Single-leaf AdamW update in fp32. Returns (new_p, new_m, new_v)."""
-    g = g.astype(jnp.float32) * clip_coef
-    m = cfg.b1 * m + (1 - cfg.b1) * g
-    v = cfg.b2 * v + (1 - cfg.b2) * jnp.square(g)
-    t = step.astype(jnp.float32) + 1.0
-    mhat = m / (1 - cfg.b1 ** t)
-    vhat = v / (1 - cfg.b2 ** t)
-    upd = mhat / (jnp.sqrt(vhat) + cfg.eps) + cfg.weight_decay * p.astype(jnp.float32)
-    new_p = (p.astype(jnp.float32) - lr * upd).astype(p.dtype)
-    return new_p, m, v
+    with jax.named_scope("adamw"):  # device-time scope, no runtime cost
+        g = g.astype(jnp.float32) * clip_coef
+        m = cfg.b1 * m + (1 - cfg.b1) * g
+        v = cfg.b2 * v + (1 - cfg.b2) * jnp.square(g)
+        t = step.astype(jnp.float32) + 1.0
+        mhat = m / (1 - cfg.b1 ** t)
+        vhat = v / (1 - cfg.b2 ** t)
+        upd = mhat / (jnp.sqrt(vhat) + cfg.eps) + cfg.weight_decay * p.astype(jnp.float32)
+        new_p = (p.astype(jnp.float32) - lr * upd).astype(p.dtype)
+        return new_p, m, v
 
 
 def global_norm(tree) -> jax.Array:
@@ -44,9 +45,10 @@ def global_norm(tree) -> jax.Array:
 def adamw_update(params, grads, state, lr, cfg: AdamWConfig
                  ) -> Tuple[Any, Dict[str, Any]]:
     """Full-tree AdamW with global-norm clipping."""
-    gnorm = global_norm(grads)
-    clip = jnp.minimum(1.0, cfg.grad_clip / jnp.maximum(gnorm, 1e-9)) \
-        if cfg.grad_clip > 0 else 1.0
+    with jax.named_scope("adamw"):
+        gnorm = global_norm(grads)
+        clip = jnp.minimum(1.0, cfg.grad_clip / jnp.maximum(gnorm, 1e-9)) \
+            if cfg.grad_clip > 0 else 1.0
     step = state["step"]
     out = jax.tree.map(
         lambda p, g, m, v: adamw_leaf(p, g, m, v, step, lr, cfg, clip),

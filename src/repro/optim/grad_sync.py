@@ -305,6 +305,9 @@ def sync_and_update(params, grads, sync_state, plan: SyncPlan,
     inv_dp = 1.0 / ss.dp_total
 
     # ---- pass 1: communicate ------------------------------------------------
+    # device-time scopes (op_name metadata, no runtime cost): "grad_sync"
+    # holds packing, collectives, scaling and the norm psums, "adamw" the
+    # update and its clip; the two never nest
     synced: Dict[str, Any] = {}
     new_sections: Dict[str, Any] = {}
     sqnorm = jnp.zeros((), jnp.float32)
@@ -312,12 +315,6 @@ def sync_and_update(params, grads, sync_state, plan: SyncPlan,
         entry = dict(sync_state["sections"][sec.name])
         ef = entry.get("ef")
         bucket = len(sec.leaf_paths) > 1
-        if bucket:
-            g = _bucket_pack(gflat, sec, n_fast)
-            k = 0
-        else:
-            g = gflat[sec.leaf_paths[0]].astype(jnp.float32)
-            k = max(sec.scatter_dim, 0)
         zero1_path = (ss.mode == "zero1" and sec.sync.strategy == "hier_striped"
                       and (bucket or (sec.scatter_dim >= 0 and full_depth(sec, ss))))
         model_axes = ((ss.model_axis,) if (ss.model_axis and sec.model_sharded)
@@ -327,31 +324,39 @@ def sync_and_update(params, grads, sync_state, plan: SyncPlan,
         # model-global shapes)
         lane_off = sec.schedule.lane_offset if sec.schedule is not None else 0
         staging = sec.schedule.staging if sec.schedule is not None else None
-        if zero1_path:
-            shard, new_ef = dfabric_reduce_scatter(
-                g, ss.fast, ss.slow_axis, sec.sync, scatter_dim=k, ef=ef,
-                schedule=sec.schedule, lane_offset=lane_off, staging=staging)
-            shard = shard * inv_dp
-            synced[sec.name] = ("shard", shard, k)
-            sqnorm = sqnorm + lax.psum(jnp.sum(jnp.square(shard)),
-                                       ss.fast + model_axes)
-        else:
-            full, new_ef = dfabric_all_reduce(
-                g, ss.fast, ss.slow_axis, sec.sync, scatter_dim=k, ef=ef,
-                schedule=sec.schedule, lane_offset=lane_off, staging=staging)
-            full = full * inv_dp
-            synced[sec.name] = ("full", full, k)
-            sq = jnp.sum(jnp.square(full))
-            if model_axes:
-                sq = lax.psum(sq, model_axes)
-            sqnorm = sqnorm + sq
+        with jax.named_scope("grad_sync"):
+            if bucket:
+                g = _bucket_pack(gflat, sec, n_fast)
+                k = 0
+            else:
+                g = gflat[sec.leaf_paths[0]].astype(jnp.float32)
+                k = max(sec.scatter_dim, 0)
+            if zero1_path:
+                shard, new_ef = dfabric_reduce_scatter(
+                    g, ss.fast, ss.slow_axis, sec.sync, scatter_dim=k, ef=ef,
+                    schedule=sec.schedule, lane_offset=lane_off, staging=staging)
+                shard = shard * inv_dp
+                synced[sec.name] = ("shard", shard, k)
+                sqnorm = sqnorm + lax.psum(jnp.sum(jnp.square(shard)),
+                                           ss.fast + model_axes)
+            else:
+                full, new_ef = dfabric_all_reduce(
+                    g, ss.fast, ss.slow_axis, sec.sync, scatter_dim=k, ef=ef,
+                    schedule=sec.schedule, lane_offset=lane_off, staging=staging)
+                full = full * inv_dp
+                synced[sec.name] = ("full", full, k)
+                sq = jnp.sum(jnp.square(full))
+                if model_axes:
+                    sq = lax.psum(sq, model_axes)
+                sqnorm = sqnorm + sq
         if new_ef is not None:
             entry["ef"] = new_ef
         new_sections[sec.name] = entry
 
-    gnorm = jnp.sqrt(sqnorm)
-    clip = jnp.minimum(1.0, opt_cfg.grad_clip / jnp.maximum(gnorm, 1e-9)) \
-        if opt_cfg.grad_clip > 0 else jnp.float32(1.0)
+    with jax.named_scope("adamw"):
+        gnorm = jnp.sqrt(sqnorm)
+        clip = jnp.minimum(1.0, opt_cfg.grad_clip / jnp.maximum(gnorm, 1e-9)) \
+            if opt_cfg.grad_clip > 0 else jnp.float32(1.0)
 
     # ---- pass 2: update -----------------------------------------------------
     new_flat: Dict[str, jax.Array] = {}
@@ -363,38 +368,41 @@ def sync_and_update(params, grads, sync_state, plan: SyncPlan,
             # parameter shard owned by this fast-tier rank (flattened
             # fastest-tier-major over all fast axes)
             idx = fast_idx if fast_idx is not None else flat_fast_index(ss)
-            if bucket:
-                p_full = _bucket_pack(pflat, sec, n_fast)
-                blk = p_full.shape[0] // n_fast
-                p_sh = lax.dynamic_slice_in_dim(p_full, idx * blk, blk, axis=0)
-            else:
-                p = pflat[sec.leaf_paths[0]]
-                blk = p.shape[k] // n_fast
-                p_sh = lax.dynamic_slice_in_dim(p, idx * blk, blk, axis=k)
+            with jax.named_scope("grad_sync"):
+                if bucket:
+                    p_full = _bucket_pack(pflat, sec, n_fast)
+                    blk = p_full.shape[0] // n_fast
+                    p_sh = lax.dynamic_slice_in_dim(p_full, idx * blk, blk, axis=0)
+                else:
+                    p = pflat[sec.leaf_paths[0]]
+                    blk = p.shape[k] // n_fast
+                    p_sh = lax.dynamic_slice_in_dim(p, idx * blk, blk, axis=k)
             new_p_sh, m, v = adamw_leaf(p_sh, g, entry["m"], entry["v"], step,
                                         lr, opt_cfg, clip)
             entry["m"], entry["v"] = m, v
             # the all-gather now carries UPDATED PARAMETERS (fused ZeRO-1);
             # gathers run up the fast tiers in reverse scatter order
-            gathered = dfabric_all_gather(new_p_sh, ss.fast,
-                                          gather_dim=(0 if bucket else k))
-            if bucket:
-                new_flat.update(_bucket_unpack(gathered, sec, pflat))
-            else:
-                new_flat[sec.leaf_paths[0]] = gathered
-        else:
-            if bucket:
+            with jax.named_scope("grad_sync"):
+                gathered = dfabric_all_gather(new_p_sh, ss.fast,
+                                              gather_dim=(0 if bucket else k))
+                if bucket:
+                    new_flat.update(_bucket_unpack(gathered, sec, pflat))
+                else:
+                    new_flat[sec.leaf_paths[0]] = gathered
+        elif bucket:
+            with jax.named_scope("grad_sync"):
                 p_full = _bucket_pack(pflat, sec, n_fast)
-                new_p, m, v = adamw_leaf(p_full, g, entry["m"], entry["v"],
-                                         step, lr, opt_cfg, clip)
-                entry["m"], entry["v"] = m, v
+            new_p, m, v = adamw_leaf(p_full, g, entry["m"], entry["v"],
+                                     step, lr, opt_cfg, clip)
+            entry["m"], entry["v"] = m, v
+            with jax.named_scope("grad_sync"):
                 new_flat.update(_bucket_unpack(new_p, sec, pflat))
-            else:
-                p = pflat[sec.leaf_paths[0]]
-                new_p, m, v = adamw_leaf(p, g, entry["m"], entry["v"], step,
-                                         lr, opt_cfg, clip)
-                entry["m"], entry["v"] = m, v
-                new_flat[sec.leaf_paths[0]] = new_p
+        else:
+            p = pflat[sec.leaf_paths[0]]
+            new_p, m, v = adamw_leaf(p, g, entry["m"], entry["v"], step,
+                                     lr, opt_cfg, clip)
+            entry["m"], entry["v"] = m, v
+            new_flat[sec.leaf_paths[0]] = new_p
         new_sections[sec.name] = entry
 
     new_params = tree_from_paths({**pflat, **new_flat})

@@ -353,6 +353,36 @@ class TrainerConfig:
     fail_at_step: Optional[int] = None  # failure injection (tests)
     seed: int = 0
     metrics_path: Optional[str] = None  # JSONL sink (repro.obs.metrics)
+    # jax.profiler trace of steps [A, B) into this directory (TensorBoard /
+    # Perfetto); None = no trace.  ``profile_steps`` None = every step.
+    profile_dir: Optional[str] = None
+    profile_steps: Optional[Tuple[int, int]] = None
+
+
+# ---------------------------------------------------------------------------
+# Recompiles, seen from the loop
+# ---------------------------------------------------------------------------
+
+#: the jax.monitoring event of one backend compile (a load from the
+#: persistent compilation cache included)
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compiles = 0  # backend compiles this process has made
+_compile_listener = False
+
+
+def _count_compile(event: str, duration: float, **kw) -> None:
+    global _compiles
+    if event == COMPILE_EVENT:
+        _compiles += 1
+
+
+def _watch_compiles() -> None:
+    """Register the process's one compile listener (jax.monitoring keeps
+    every listener for the life of the process)."""
+    global _compile_listener
+    if not _compile_listener:
+        jax.monitoring.register_event_duration_secs_listener(_count_compile)
+        _compile_listener = True
 
 
 class Trainer:
@@ -441,6 +471,15 @@ class Trainer:
     # ---- the loop -------------------------------------------------------------------
     def train(self, params=None, opt=None, start_step: int = 0
               ) -> Dict[str, Any]:
+        """Steps from ``start_step`` to ``cfg.steps`` (or a preemption).
+
+        Each step is a ``jax.profiler.StepTraceAnnotation("train")`` holding
+        one span per host action (``train.batch``, ``train.device_put``,
+        ``train.dispatch``, ``train.fetch``: the wait for the device, and
+        ``train.checkpoint``), on the device trace's clock.  A step that
+        compiled is logged as a ``compile`` record and counted in
+        ``compiles``."""
+        _watch_compiles()
         restored = self.try_restore()
         if params is None:
             if restored is not None:
@@ -452,40 +491,67 @@ class Trainer:
             if self.cfg.mode == "dfabric" else self.bshard
 
         step = start_step
+        profiling = False
+        prof_lo, prof_hi = self.cfg.profile_steps or (0, self.cfg.steps)
         try:
             while step < self.cfg.steps:
-                t0 = time.perf_counter()
-                host_batch = self.pipeline.batch_at(step)
-                batch = {k: jax.device_put(v, bshard[k]) for k, v in host_batch.items()}
-                params, opt, metrics = self.step_fn(params, opt, batch,
-                                                    jnp.int32(step))
-                metrics = {k: float(v) for k, v in metrics.items()}
-                dt = time.perf_counter() - t0
-                self.watchdog.update(step, dt)
-                metrics.update(step=step, dt=dt)
-                self.metrics_log.append(metrics)
-                self.metrics.log("train_step", **metrics)
-                self.metrics.inc("steps")
-                self.metrics.gauge("loss", metrics["loss"])
-                if self.cfg.log_every and step % self.cfg.log_every == 0:
-                    self.metrics.info(
-                        f"step {step:5d} loss {metrics['loss']:.4f} "
-                        f"gnorm {metrics['grad_norm']:.3f} dt {dt*1e3:.1f}ms")
-                step += 1
-                if self.ckpt and step % self.cfg.ckpt_every == 0:
-                    self.ckpt.save(step, {
-                        "params": params, "opt": opt,
-                        "data_state": self.pipeline.state_dict(step)})
-                if self.cfg.fail_at_step is not None and step >= self.cfg.fail_at_step:
-                    raise SimulatedFailure(f"injected failure at step {step}")
-                if self._preempted:
-                    if self.ckpt:
-                        self.ckpt.save(step, {
-                            "params": params, "opt": opt,
-                            "data_state": self.pipeline.state_dict(step)},
-                            blocking=True)
-                    break
+                if profiling and step >= prof_hi:
+                    jax.profiler.stop_trace()
+                    profiling = False
+                if (self.cfg.profile_dir is not None and not profiling
+                        and prof_lo <= step < prof_hi):
+                    opts = jax.profiler.ProfileOptions()
+                    opts.python_tracer_level = 0  # keep the host loop's pace
+                    jax.profiler.start_trace(self.cfg.profile_dir,
+                                             profiler_options=opts)
+                    profiling = True
+                with jax.profiler.StepTraceAnnotation("train", step_num=step):
+                    t0 = time.perf_counter()
+                    compiled_before = _compiles
+                    with jax.profiler.TraceAnnotation("train.batch"):
+                        host_batch = self.pipeline.batch_at(step)
+                    with jax.profiler.TraceAnnotation("train.device_put"):
+                        batch = {k: jax.device_put(v, bshard[k])
+                                 for k, v in host_batch.items()}
+                    with jax.profiler.TraceAnnotation("train.dispatch"):
+                        params, opt, metrics = self.step_fn(params, opt, batch,
+                                                            jnp.int32(step))
+                    with jax.profiler.TraceAnnotation("train.fetch"):
+                        metrics = {k: float(v) for k, v in metrics.items()}
+                    dt = time.perf_counter() - t0
+                    if _compiles > compiled_before:
+                        self.metrics.log("compile", step=step,
+                                         compiles=_compiles - compiled_before)
+                        self.metrics.inc("compiles", _compiles - compiled_before)
+                    self.watchdog.update(step, dt)
+                    metrics.update(step=step, dt=dt)
+                    self.metrics_log.append(metrics)
+                    self.metrics.log("train_step", **metrics)
+                    self.metrics.inc("steps")
+                    self.metrics.gauge("loss", metrics["loss"])
+                    if self.cfg.log_every and step % self.cfg.log_every == 0:
+                        self.metrics.info(
+                            f"step {step:5d} loss {metrics['loss']:.4f} "
+                            f"gnorm {metrics['grad_norm']:.3f} dt {dt*1e3:.1f}ms")
+                    step += 1
+                    if self.ckpt and step % self.cfg.ckpt_every == 0:
+                        with jax.profiler.TraceAnnotation("train.checkpoint"):
+                            self.ckpt.save(step, {
+                                "params": params, "opt": opt,
+                                "data_state": self.pipeline.state_dict(step)})
+                    if self.cfg.fail_at_step is not None and step >= self.cfg.fail_at_step:
+                        raise SimulatedFailure(f"injected failure at step {step}")
+                    if self._preempted:
+                        if self.ckpt:
+                            with jax.profiler.TraceAnnotation("train.checkpoint"):
+                                self.ckpt.save(step, {
+                                    "params": params, "opt": opt,
+                                    "data_state": self.pipeline.state_dict(step)},
+                                    blocking=True)
+                        break
         finally:
+            if profiling:
+                jax.profiler.stop_trace()
             # emit the final 'summary' record and release the JSONL handle
             self.metrics.close()
             # drain the async checkpoint write even when a step raised: a

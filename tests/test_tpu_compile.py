@@ -4,16 +4,21 @@ Each Pallas kernel at a real model width, and the one-chip ``qwen2-0.5b``
 DFabric train step at ``chip_smoke.py``'s batch, compiled by the TPU
 compiler for ``v5e:2x2``: tiling, VMEM and HBM refusals show up here
 before any chip time is spent.  Nothing runs, so nothing here says
-anything about results or times.
+anything about results or times.  The compiled text also shows that the
+program's ``jax.named_scope``s (layers, optimizer, collective legs, on the
+four-chip step too) and each kernel's name survive the TPU compiler,
+which is what a device trace is read by.
 
 The topology is described inside a module-scoped fixture, never while a
 module is imported: only one process at a time may load the TPU library.
 """
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
@@ -73,15 +78,24 @@ def _kernel_call(name, one_chip):
 @pytest.mark.parametrize("name", ["flash_attention", "wkv6", "mamba_scan"])
 def test_kernel_compiles_for_v5e(name, one_chip):
     fn, args = _kernel_call(name, one_chip)
-    compiled = jax.jit(lambda *a: fn(*a)).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(lambda *a: fn(*a)).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's pallas_call name names its custom call, so a device
+    # trace finds the kernel by name
+    assert re.search(rf"%{name}(\.\d+)? = .*custom-call\(", text)
 
 
-def test_one_chip_train_step_compiles_for_v5e(topo):
+def _chip_smoke():
     spec = importlib.util.spec_from_file_location(
         "chip_smoke", os.path.join(REPO, "chip_smoke.py"))
     cs = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(cs)
+    return cs
+
+
+def _compile_train_step(topo, mesh_shape, B, S):
+    """The DFabric train step of ``chip_smoke.py``'s model at batch x seq,
+    compiled for the described chips of a ``mesh_shape`` mesh."""
     from repro.configs.base import ShapeConfig, get_arch
     from repro.launch.cells import cell_settings
     from repro.models.registry import build_model
@@ -89,11 +103,12 @@ def test_one_chip_train_step_compiles_for_v5e(topo):
                                           batch_sharding, mesh_info)
     from repro.utils.jax_compat import make_mesh
 
+    cs = _chip_smoke()
     arch = get_arch(cs.ARCH)
-    B, S = cs.TRAIN["batch"], cs.TRAIN["seq"]
     shape = ShapeConfig("chip_smoke", S, B, "train")
     model = build_model(arch, cell_settings(arch, shape))
-    mesh = make_mesh((1, 1, 1), cs.MESH_AXES, devices=topo.devices[:1])
+    n = int(np.prod(mesh_shape))
+    mesh = make_mesh(mesh_shape, cs.MESH_AXES, devices=topo.devices[:n])
     tr = Trainer(model, mesh, shape, TrainerConfig(mode="dfabric"))
 
     def with_sharding(shapes, shardings):
@@ -110,7 +125,61 @@ def test_one_chip_train_step_compiles_for_v5e(topo):
     batch = {k: jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=bsh[k])
              for k in ("tokens", "labels")}
     step = jax.ShapeDtypeStruct((), jnp.int32, sharding=NamedSharding(mesh, P()))
-    compiled = tr.step_fn.lower(params, opt, batch, step).compile()
-    ma = compiled.memory_analysis()
+    return tr.step_fn.lower(params, opt, batch, step).compile()
+
+
+@pytest.fixture(scope="module")
+def one_chip_step(topo):
+    cs = _chip_smoke()
+    return _compile_train_step(topo, (1, 1, 1), cs.TRAIN["batch"], cs.TRAIN["seq"])
+
+
+@pytest.fixture(scope="module")
+def dp4_step(topo):
+    """The four-chip step on a (pod, data) = (2, 2) mesh,
+    one row per chip, at a shorter sequence."""
+    return _compile_train_step(topo, (2, 2, 1), 4, 512)
+
+
+def test_one_chip_train_step_compiles_for_v5e(one_chip_step):
+    ma = one_chip_step.memory_analysis()
     # the compiler refuses a program over HBM; this pins what it accepted
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 16e9
+
+
+def _phase(op_name):
+    if "rematted_computation" in op_name:
+        return "remat"
+    return "backward" if "transpose(" in op_name else "forward"
+
+
+@pytest.mark.parametrize("scope,phases", [
+    ("attention", {"forward", "remat", "backward"}),
+    ("mlp", {"forward", "remat", "backward"}),
+    ("lm_loss", {"forward", "remat", "backward"}),
+    ("grad_sync", {"forward"}),
+    ("adamw", {"forward"}),
+])
+def test_one_chip_train_step_carries_scopes(one_chip_step, scope, phases):
+    """Each layer's scope reaches the compiled step's op_name metadata in
+    each phase it runs in; the optimizer's two scopes never nest."""
+    names = re.findall(r'op_name="([^"]*)"', one_chip_step.as_text())
+
+    def has(o, sc):  # a path component, or wrapped: jvp(lm_loss)
+        return re.search(rf"[/(]{sc}[/)]", o) is not None
+
+    assert {_phase(o) for o in names if has(o, scope)} == phases
+    assert not [o for o in names if has(o, "grad_sync") and has(o, "adamw")]
+
+
+@pytest.mark.parametrize("leg,op", [("reduce_scatter", "reduce-scatter"),
+                                    ("slow_chunk", "all-reduce"),
+                                    ("all_gather", "all-gather")])
+def test_dp4_train_step_carries_leg_scopes(dp4_step, leg, op):
+    """Each DFabric leg's collectives carry the leg's scope inside
+    ``grad_sync`` in the four-chip step, so each tier gets its own time."""
+    text = dp4_step.as_text()
+    found = [ln for ln in text.splitlines()
+             if re.search(rf"\s{op}(-start)?\(", ln)
+             and re.search(rf'op_name="[^"]*/grad_sync/(\S*/)?{leg}/', ln)]
+    assert found, f"no {op} scoped grad_sync/{leg}"

@@ -4,8 +4,10 @@ Parameters are nested dicts of jnp arrays.  Every layer has an
 ``init_*(key, ...) -> params`` and an ``apply`` function.  Attention is
 GQA-aware and has three implementations:
 
-  * ``masked``  — dense S x S with a causal mask (paper-faithful baseline;
-                  exact-but-2x FLOPs for causal),
+  * ``masked``  — chunked flash attention with a hand-written backward
+                  (``_flash``): causal self-attention visits only the
+                  tiles on or below the diagonal, and only those on it
+                  build a mask,
   * ``tri``     — static triangular decomposition (recursive halving with
                   online-softmax merge; rectangles carry zero wasted FLOPs)
                   — the beyond-paper optimization logged in EXPERIMENTS §Perf,
@@ -232,6 +234,159 @@ def _causal_tri(q, k, v, *, block: int, scale: float, q_off: int, kv_off: int,
     return m, l, o
 
 
+def _fit(n: int, want: int) -> int:
+    """The largest chunk <= ``want`` that divides ``n``."""
+    c = min(want, n)
+    while c > 1 and n % c != 0:
+        c -= 1
+    return max(c, 1)
+
+
+def _row_spans(nq: int, nk: int, q_chunk: int, kv_chunk: int, causal: bool):
+    """Per q-chunk ``i``, (full, live): kv tiles ``[0, full)`` see every
+    key, ``[full, live)`` need the causal mask, and tiles from ``live`` on
+    are all masked and skipped.  Without the mask every tile is visited."""
+    if not causal:
+        return [(nk, nk)] * nq
+    return [((i * q_chunk + 1) // kv_chunk, ((i + 1) * q_chunk - 1) // kv_chunk + 1)
+            for i in range(nq)]
+
+
+def attn_tile_counts(Sq: int, Sk: int, q_chunk: int, kv_chunk: int,
+                     causal: bool) -> Tuple[int, int]:
+    """(tiles visited, tiles in the grid) of ``attend(impl="masked")`` for
+    the chunk sizes it is given (fitted to the lengths as ``attend`` fits
+    them): causal self-attention skips the tiles above the diagonal."""
+    qc, kc = _fit(Sq, q_chunk), _fit(Sk, kv_chunk)
+    nq, nk = Sq // qc, Sk // kc
+    spans = _row_spans(nq, nk, qc, kc, causal and Sq == Sk)
+    return sum(live for _, live in spans), nq * nk
+
+
+def _loop(lo: int, hi: int, body, carry):
+    """``body(j, carry)`` for j in [lo, hi): a while loop, inlined when it
+    runs at most once."""
+    if hi - lo > 1:
+        return lax.fori_loop(lo, hi, body, carry)
+    for j in range(lo, hi):
+        carry = body(j, carry)
+    return carry
+
+
+def _tile_scores(qi, kj, i, j, *, masked: bool, G: int, scale: float):
+    """f32 scores of q tile ``qi`` (B, KV, G*qc, hd), rows g-major, against
+    k tile ``kj`` (B, KV, kc, hd); keys after the query are -inf where
+    ``masked``."""
+    s = jnp.einsum("bkrh,bksh->bkrs", qi, kj,
+                   preferred_element_type=jnp.float32) * scale
+    if masked:
+        R, kc = qi.shape[2], kj.shape[2]
+        qc = R // G
+        qpos = i * qc + lax.rem(lax.broadcasted_iota(jnp.int32, (R, kc), 0), qc)
+        kpos = j * kc + lax.broadcasted_iota(jnp.int32, (R, kc), 1)
+        s = jnp.where(qpos >= kpos, s, -jnp.inf)
+    return s
+
+
+def _flash_forward(qt, kt, vt, causal: bool, G: int, scale: float):
+    """Online-softmax forward over the live tiles.
+
+    qt: (nq, B, KV, G*qc, hd); kt, vt: (nk, B, KV, kc, hd); ``causal``
+    masks keys after the query (self-attention, nq*qc == nk*kc).  Returns
+    the normalised f32 output (nq, B, KV, G*qc, hd) and the per-row
+    log-sum-exp (nq, B, KV, G*qc).  Every row sees key 0 in its first
+    tile, so the running max is finite from then on and needs no guard.
+    """
+    nq, B, KV, R, hd = qt.shape
+    nk, kc = kt.shape[0], kt.shape[3]
+    os_, lses = [], []
+    for i, (full, live) in enumerate(_row_spans(nq, nk, R // G, kc, causal)):
+        qi = qt[i]
+
+        def body(j, carry, masked):
+            m, l, o = carry
+            s = _tile_scores(qi, lax.dynamic_index_in_dim(kt, j, 0, False), i, j,
+                             masked=masked, G=G, scale=scale)
+            mnew = jnp.maximum(m, jnp.max(s, axis=-1))
+            alpha = jnp.exp(m - mnew)
+            p = jnp.exp(s - mnew[..., None])
+            o = o * alpha[..., None] + jnp.einsum(
+                "bkrs,bksh->bkrh", p, lax.dynamic_index_in_dim(vt, j, 0, False),
+                preferred_element_type=jnp.float32)
+            return mnew, l * alpha + jnp.sum(p, axis=-1), o
+
+        carry = (jnp.full((B, KV, R), -jnp.inf, jnp.float32),
+                 jnp.zeros((B, KV, R), jnp.float32),
+                 jnp.zeros((B, KV, R, hd), jnp.float32))
+        carry = _loop(0, full, partial(body, masked=False), carry)
+        m, l, o = _loop(full, live, partial(body, masked=True), carry)
+        os_.append(o / l[..., None])
+        lses.append(m + jnp.log(l))
+    return jnp.stack(os_), jnp.stack(lses)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(qt, kt, vt, causal: bool, G: int, scale: float):
+    """Exact attention in the tiled layout of :func:`_flash_forward`,
+    differentiated by the FlashAttention-2 backward: no score tile is
+    kept past its iteration, and tiles above the diagonal are skipped."""
+    return _flash_forward(qt, kt, vt, causal, G, scale)[0]
+
+
+def _flash_fwd(qt, kt, vt, causal, G, scale):
+    o, lse = _flash_forward(qt, kt, vt, causal, G, scale)
+    return o, (qt, kt, vt, o, lse)
+
+
+def _flash_bwd(causal, G, scale, res, do):
+    """Per live tile: recompute p = exp(s - lse), then dV += pᵀdO,
+    dP = dO Vᵀ, dS = p (dP - D) with D = rowsum(dO * o), dQ += dS K,
+    dK += dSᵀQ.  The matmuls take the operand dtypes autodiff of the
+    chunked online softmax gave them (f32 p, dO and dS; q, k, v as
+    stored); every sum accumulates in f32."""
+    qt, kt, vt, o, lse = res
+    nq, B, KV, R, hd = qt.shape
+    nk, kc = kt.shape[0], kt.shape[3]
+    D = jnp.sum(do * o, axis=-1)
+    dk = jnp.zeros((nk, B, KV, kc, hd), jnp.float32)
+    dv = jnp.zeros((nk, B, KV, kc, hd), jnp.float32)
+    dqs = []
+
+    def add_at(acc, j, x):
+        return lax.dynamic_update_index_in_dim(
+            acc, lax.dynamic_index_in_dim(acc, j, 0, False) + x, j, 0)
+
+    for i, (full, live) in enumerate(_row_spans(nq, nk, R // G, kc, causal)):
+        qi, doi, lse_i, D_i = qt[i], do[i], lse[i], D[i]
+
+        def body(j, carry, masked):
+            dq, dk, dv = carry
+            kj = lax.dynamic_index_in_dim(kt, j, 0, False)
+            vj = lax.dynamic_index_in_dim(vt, j, 0, False)
+            s = _tile_scores(qi, kj, i, j, masked=masked, G=G, scale=scale)
+            p = jnp.exp(s - lse_i[..., None])
+            dv = add_at(dv, j, jnp.einsum("bkrs,bkrh->bksh", p, doi,
+                                          preferred_element_type=jnp.float32))
+            dp = jnp.einsum("bkrh,bksh->bkrs", doi, vj,
+                            preferred_element_type=jnp.float32)
+            ds = p * (dp - D_i[..., None]) * scale
+            dq = dq + jnp.einsum("bkrs,bksh->bkrh", ds, kj,
+                                 preferred_element_type=jnp.float32)
+            dk = add_at(dk, j, jnp.einsum("bkrs,bkrh->bksh", ds, qi,
+                                          preferred_element_type=jnp.float32))
+            return dq, dk, dv
+
+        carry = (jnp.zeros((B, KV, R, hd), jnp.float32), dk, dv)
+        carry = _loop(0, full, partial(body, masked=False), carry)
+        dq, dk, dv = _loop(full, live, partial(body, masked=True), carry)
+        dqs.append(dq)
+    return (jnp.stack(dqs).astype(qt.dtype), dk.astype(kt.dtype),
+            dv.astype(vt.dtype))
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
 def attend(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
            impl: str = "masked", block: int = 1024,
            q_chunk: int = 1024, kv_chunk: int = 1024,
@@ -260,22 +415,29 @@ def attend(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
         from repro.kernels.flash_attention import ops as fa_ops
         out = fa_ops.flash_attention(qg, k, v, causal=causal)
         return out.reshape(B, Sq, H, hd)
-    def _fit(n: int, want: int) -> int:
-        c = min(want, n)
-        while c > 1 and n % c != 0:
-            c -= 1
-        return max(c, 1)
-
-    if causal and impl == "tri" and Sq == k.shape[1] and Sq > block and Sq % block == 0:
-        m, l, o = _causal_tri(qg, k, v, block=block, scale=scale, q_off=0,
-                              kv_off=0, q_chunk=_fit(Sq, q_chunk),
-                              kv_chunk=_fit(Sq, kv_chunk))
-    else:
-        mask = "causal" if (causal and Sq == k.shape[1]) else None
-        m, l, o = _attn_rect_chunked(qg, k, v, q_chunk=_fit(Sq, q_chunk),
-                                     kv_chunk=_fit(k.shape[1], kv_chunk),
-                                     scale=scale, mask=mask)
-    return _finalize(m, l, o, q.dtype).reshape(B, Sq, H, hd)
+    Sk = k.shape[1]
+    if impl == "tri":
+        if causal and Sq == Sk and Sq > block and Sq % block == 0:
+            m, l, o = _causal_tri(qg, k, v, block=block, scale=scale, q_off=0,
+                                  kv_off=0, q_chunk=_fit(Sq, q_chunk),
+                                  kv_chunk=_fit(Sq, kv_chunk))
+        else:
+            mask = "causal" if (causal and Sq == Sk) else None
+            m, l, o = _attn_rect_chunked(qg, k, v, q_chunk=_fit(Sq, q_chunk),
+                                         kv_chunk=_fit(Sk, kv_chunk),
+                                         scale=scale, mask=mask)
+        return _finalize(m, l, o, q.dtype).reshape(B, Sq, H, hd)
+    # the tiled layout: q rows (g, position) of each q-chunk side by side,
+    # so each (chunk, kv head) is one (G*qc) x kc matmul
+    qc, kc = _fit(Sq, q_chunk), _fit(Sk, kv_chunk)
+    nq, nk = Sq // qc, Sk // kc
+    qt = qg.reshape(B, nq, qc, KV, G, hd).transpose(1, 0, 3, 4, 2, 5)
+    kt = k.reshape(B, nk, kc, KV, hd).transpose(1, 0, 3, 2, 4)
+    vt = v.reshape(B, nk, kc, KV, hd).transpose(1, 0, 3, 2, 4)
+    o = _flash(qt.reshape(nq, B, KV, G * qc, hd), kt, vt, causal and Sq == Sk,
+               G, scale)
+    o = o.reshape(nq, B, KV, G, qc, hd).transpose(1, 0, 4, 2, 3, 5)
+    return o.reshape(B, Sq, H, hd).astype(q.dtype)
 
 
 def attend_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,

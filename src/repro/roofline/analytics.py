@@ -24,6 +24,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
 from repro.configs.base import ArchConfig, ShapeConfig
+from repro.models.layers import attn_tile_counts
 from repro.models.registry import Model, count_active_params, count_params
 from repro.models.transformer import ModelSettings, group_size, layer_is_moe, layer_kind
 
@@ -55,7 +56,9 @@ def _attn_core_factor(S: int, st: ModelSettings, causal: bool) -> float:
     """Fraction of the full S x S attention actually computed."""
     if not causal:
         return 1.0
-    if st.attn_impl == "tri" and S > st.attn_block and S % st.attn_block == 0:
+    if st.attn_impl == "tri":
+        if S <= st.attn_block or S % st.attn_block:
+            return 1.0  # falls back to the chunked path over every tile
         # rectangles = exactly the strict lower triangle; leaf diagonal
         # blocks are computed dense-masked (half wasted within each).
         nb = S // st.attn_block
@@ -63,7 +66,9 @@ def _attn_core_factor(S: int, st: ModelSettings, causal: bool) -> float:
     if st.attn_impl == "pallas":
         nb = max(S // 128, 1)
         return 0.5 + 0.5 / nb
-    return 1.0  # masked-dense computes everything
+    # masked: the tiles on or below the diagonal, each computed whole
+    visited, total = attn_tile_counts(S, S, st.attn_chunk, st.attn_chunk, causal)
+    return visited / total
 
 
 def layer_fwd_cost(arch: ArchConfig, B: float, S: int, st: ModelSettings,

@@ -64,6 +64,83 @@ def test_attention_vs_kernel_ref():
                                rtol=2e-4, atol=2e-4)
 
 
+# (Sq, Sk, H, KV, q_chunk, kv_chunk, causal, gqa_repeat, dtype); the chunk
+# counts per side follow from the lengths and chunks
+_FLASH_CASES = {
+    "causal-1x1": (64, 64, 4, 2, 64, 64, True, False, "float32"),
+    "causal-2x2": (128, 128, 4, 2, 64, 64, True, False, "float32"),
+    "causal-4x4": (128, 128, 4, 2, 32, 32, True, False, "float32"),
+    "causal-2x4": (128, 128, 4, 2, 64, 32, True, False, "float32"),
+    "causal-4x2": (128, 128, 4, 2, 32, 64, True, False, "float32"),
+    "full-4x4": (128, 128, 4, 2, 32, 32, False, False, "float32"),
+    "cross-2x4": (64, 128, 4, 2, 32, 32, False, False, "float32"),
+    "cross-4x1": (128, 32, 4, 2, 32, 32, False, False, "float32"),
+    "gqa-g4": (128, 128, 8, 2, 32, 32, True, False, "float32"),
+    "gqa-repeat": (128, 128, 8, 2, 64, 64, True, True, "float32"),
+    "mha-g1": (128, 128, 4, 4, 32, 32, True, False, "float32"),
+    "fit-shrinks": (96, 96, 4, 2, 64, 64, True, False, "float32"),
+    "bf16-causal": (128, 128, 4, 2, 32, 32, True, False, "bfloat16"),
+    "bf16-cross": (64, 128, 4, 2, 32, 64, False, False, "bfloat16"),
+}
+
+
+@pytest.mark.parametrize("case", list(_FLASH_CASES))
+def test_masked_attention_and_grads_match_refs(case):
+    """``attend(impl="masked")`` (the hand-written flash backward) gives the
+    output and dq, dk, dv of ``jax.vjp`` of the dense f32 reference and of
+    ``impl="tri"``.  f32 agrees to f32 rounding; bf16 inputs are held to
+    2^-6 of the largest magnitude, four times one bf16 rounding (2^-8) of
+    each result."""
+    from repro.kernels.flash_attention.ref import attention_ref
+    from repro.models.layers import attend
+    Sq, Sk, H, KV, qc, kc, causal, rep, dt = _FLASH_CASES[case]
+    B, hd = 2, 16
+    ks = jax.random.split(jax.random.key(3), 4)
+    q = jax.random.normal(ks[0], (B, Sq, H, hd)).astype(dt)
+    k = jax.random.normal(ks[1], (B, Sk, KV, hd)).astype(dt)
+    v = jax.random.normal(ks[2], (B, Sk, KV, hd)).astype(dt)
+    g = jax.random.normal(ks[3], (B, Sq, H, hd)).astype(dt)
+
+    def masked(q, k, v):
+        return attend(q, k, v, causal=causal, impl="masked", q_chunk=qc,
+                      kv_chunk=kc, gqa_repeat=rep)
+
+    def tri(q, k, v):
+        return attend(q, k, v, causal=causal, impl="tri", block=Sq // 2,
+                      q_chunk=qc, kv_chunk=kc, gqa_repeat=rep)
+
+    def ref(q, k, v):  # (B, S, heads, hd) in and out, f32 throughout
+        f = lambda x: jnp.moveaxis(x.astype(jnp.float32), 1, 2)
+        return jnp.moveaxis(attention_ref(f(q), f(k), f(v), causal=causal), 2, 1)
+
+    def out_and_grads(fn):
+        @jax.jit
+        def run(q, k, v, g):
+            o, vjp = jax.vjp(fn, q, k, v)
+            return (o, *vjp(g.astype(o.dtype)))
+        return [np.asarray(x.astype(jnp.float32)) for x in run(q, k, v, g)]
+
+    got = out_and_grads(masked)
+    tol = 2e-4 if dt == "float32" else 2.0 ** -6
+    for want in (out_and_grads(ref), out_and_grads(tri)):
+        for name, a, b in zip(("o", "dq", "dk", "dv"), got, want):
+            assert a.dtype == b.dtype and a.shape == b.shape, name
+            np.testing.assert_allclose(a, b, rtol=tol,
+                                       atol=tol * float(np.abs(b).max()),
+                                       err_msg=f"{case}: {name}")
+
+
+@pytest.mark.parametrize("S,causal,want", [
+    (2048, True, (3, 4)), (4096, True, (10, 16)),
+    (2048, False, (4, 4)), (4096, False, (16, 16)),
+])
+def test_masked_attention_tile_schedule(S, causal, want):
+    """With 1024-wide chunks causal self-attention visits only the tiles on
+    or below the diagonal; without the mask it visits every tile."""
+    from repro.models.layers import attn_tile_counts
+    assert attn_tile_counts(S, S, 1024, 1024, causal) == want
+
+
 # ---------------------------------------------------------------------------
 # MoE dispatch correctness vs brute force
 # ---------------------------------------------------------------------------

@@ -147,6 +147,15 @@ def test_one_chip_train_step_compiles_for_v5e(one_chip_step):
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 16e9
 
 
+def test_one_chip_train_step_stacks_no_score_tiles(one_chip_step):
+    """The masked attention's backward recomputes its 1024 x 1024 score
+    tiles: the compiled step writes none of them into a stack."""
+    stacked = [ln for ln in one_chip_step.as_text().splitlines()
+               if "dynamic-update-slice" in ln
+               and re.match(r"\s*(ROOT )?%\S+ = \w+\[(\d+,)*1024,1024\]", ln)]
+    assert not stacked, stacked[:3]
+
+
 def _phase(op_name):
     if "rematted_computation" in op_name:
         return "remat"

@@ -73,7 +73,8 @@ def calibrate(cell, seeds, controls: int, program: bool = True) -> dict:
                 x.delete()
         t1 = time.perf_counter()
         expected = bcell.reference_batches(cell, seed)
-        ref = rtrain.run(cell.cfg, cell.workload["optimizer"], seed, expected)
+        ref = rtrain.run(cell.reference, cell.cfg, cell.workload["optimizer"],
+                         seed, expected)
         row.update(program_s=t1 - t0, reference_s=time.perf_counter() - t1,
                    reference_losses=ref.losses)
         if program:
@@ -83,8 +84,8 @@ def calibrate(cell, seeds, controls: int, program: bool = True) -> dict:
                               "losses": prog.losses, "worst_leaves": worst(prog, ref)}
         if i < controls:
             for name, kw in variants.items():
-                other = rtrain.run(cell.cfg, cell.workload["optimizer"], seed,
-                                   expected, **kw)
+                other = rtrain.run(cell.reference, cell.cfg,
+                                   cell.workload["optimizer"], seed, expected, **kw)
                 row[name] = {**check.gaps(other, ref), "losses": other.losses,
                              "worst_leaves": worst(other, ref)}
         out["seeds"][str(seed)] = row

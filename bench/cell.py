@@ -13,6 +13,11 @@ The benchmark opens its spans around the calls the trainer makes into the
 layers below it: ``bench.host_input`` around the pipeline's ``batch_at``
 (a delegating pipeline handed to ``Trainer(data_pipeline=)``) and
 ``bench.step_call`` around ``step_fn``.  ``bench.window`` spans the window.
+
+Nothing here belongs to one family of models: the configuration's
+``program`` module maps its file onto the program's arch, and its
+``reference`` module gives the weight layout, the reference loss and the
+FLOP per token (``bench/spec.py``).
 """
 from __future__ import annotations
 
@@ -33,43 +38,20 @@ import numpy as np
 
 from bench import check, flops, spec, trace, traffic
 from bench.reference import params as rparams, train as rtrain
-
-#: families and mechanisms the plain reference implements
-REFERENCE_ARCH = dict(family="dense", activation="silu", glu=True,
-                      norm="rmsnorm", positional="rope", moe=None,
-                      is_encdec=False)
-
-
-def arch_of(cfg: dict):
-    """The program's ``ArchConfig`` with every size of the config file."""
-    from repro.configs.base import get_arch
-
-    arch = get_arch(cfg["arch"]).replace(
-        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"], n_kv_heads=cfg["num_key_value_heads"],
-        head_dim=cfg["head_dim"], d_ff=cfg["intermediate_size"],
-        vocab=cfg["vocab_size"], qkv_bias=cfg["attention_bias"],
-        qk_norm=cfg["qk_norm"], norm_eps=cfg["rms_norm_eps"],
-        rope_theta=cfg["rope_theta"], tie_embeddings=cfg["tie_word_embeddings"],
-        activation=cfg["hidden_act"])
-    for k, want in REFERENCE_ARCH.items():
-        if getattr(arch, k) != want:
-            raise ValueError(f"{cfg['arch']}: {k}={getattr(arch, k)!r}, the "
-                             f"reference implements {want!r}")
-    return arch
-
-
 class SpanPipeline:
-    """Delegates to the program's pipeline; times each ``batch_at`` and keeps
-    the batches of the first ``keep`` steps for the comparison."""
+    """Delegates to the program's pipeline; times each ``batch_at`` (when it
+    starts and how long it takes: one call a step) and keeps the batches of
+    the first ``keep`` steps for the comparison."""
 
     def __init__(self, inner, keep: int):
         self.inner, self.keep = inner, keep
+        self.starts: List[float] = []
         self.durations: List[float] = []
         self.kept: Dict[int, Dict[str, np.ndarray]] = {}
 
     def batch_at(self, step: int):
         t0 = time.perf_counter()
+        self.starts.append(t0)
         with jax.profiler.TraceAnnotation("bench.host_input"):
             out = self.inner.batch_at(step)
         self.durations.append(time.perf_counter() - t0)
@@ -127,7 +109,7 @@ def build(cell: spec.Cell, seed: int) -> Setup:
 
     t0 = time.perf_counter()
     cfg, mix, wl = cell.cfg, cell.mix, cell.workload
-    arch = arch_of(cfg)
+    arch = cell.program.arch_of(cfg)
     shape = ShapeConfig(cell.name, mix["seq_len"], mix["global_batch"], "train")
     settings = dataclasses.replace(
         cell_settings(arch, shape, attn_impl=cfg["attn_impl"], remat=cfg["remat"]),
@@ -150,7 +132,7 @@ def build(cell: spec.Cell, seed: int) -> Setup:
     t2 = time.perf_counter()
     trainer.step_fn = SpanStep(trainer.step_fn)
 
-    lay = rparams.layout(cfg)
+    lay = cell.reference.layout(cfg)
     shapes = {k: tuple(v.shape) for k, v in tree_paths(model.param_shapes()).items()}
     want = {k: v[0] for k, v in lay.items()}
     if shapes != want:
@@ -241,6 +223,9 @@ class Window:
     seconds: float
     compiles: int
     host_input_s: List[float]
+    #: host seconds of each step, from one ``batch_at`` to the next; the
+    #: first from the window's start, the last to its end
+    step_s: List[float]
     trace: Optional[trace.Reduced] = None
 
 
@@ -286,8 +271,9 @@ def measure(s: Setup, params, opt, seconds: float, trace_dir: Optional[Path],
         counter.active = False
         if trace_dir is not None:
             jax.profiler.stop_trace()
+    marks = [t0, *s.pipeline.starts[done_before + 1:], t1]
     w = Window(out["step"] - start, t1 - t0, counter.n,
-               s.pipeline.durations[done_before:])
+               s.pipeline.durations[done_before:], list(np.diff(marks)))
     if trace_dir is not None:
         w.trace = trace.read(trace.find_xspace(str(trace_dir)))
         if w.trace.ops:
@@ -330,7 +316,8 @@ def compare(s: Setup, seed: int, prog: rtrain.Readings):
     """Run the reference and judge; returns (correct, checks)."""
     expected = reference_batches(s.cell, seed)
     delivered = [s.pipeline.kept.get(t, {}) for t in range(len(expected))]
-    ref = rtrain.run(s.cell.cfg, s.cell.workload["optimizer"], seed, expected)
+    ref = rtrain.run(s.cell.reference, s.cell.cfg, s.cell.workload["optimizer"],
+                     seed, expected)
     numbers = {**check.gaps(prog, ref), **check.batch_faults(delivered, expected)}
     return check.judge(numbers, {**s.cell.workload["limits"],
                                  "batch_mismatch": 0.0, "repeated_rows": 0.0})
@@ -370,7 +357,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool,
         tps = tokens / w.seconds
         values = {"tokens_per_s": tps, "setup_s": setup_s}
         if pk is not None:
-            values["mfu"] = 100.0 * tps * flops.train_flops_per_token(
+            values["mfu"] = 100.0 * tps * cell.reference.train_flops_per_token(
                 cell.cfg, cell.mix["seq_len"]) / (cell.chips * pk["bf16_flops_per_s"])
         metrics = {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
                    for m in cell.end_to_end if m["name"] in values}
@@ -389,6 +376,8 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool,
             "device_ops": [], "idle_gaps": []}
     print(f"window steps {w.steps} seconds {w.seconds!r} compiles {w.compiles} "
           f"host_input_s_mean {statistics.mean(w.host_input_s) if w.host_input_s else 0!r}",
+          file=sys.stderr)
+    print("window step_ms " + " ".join(f"{1e3 * x:.2f}" for x in w.step_s),
           file=sys.stderr)
     result.update(metrics=metrics, device=dev, checks=checks)
     return result

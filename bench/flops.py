@@ -3,7 +3,9 @@
 These counts are what the algorithm needs, not what an implementation
 happens to compute: recomputation under rematerialisation, the masked half
 of a full attention square and padding do not count.  Utilisation and
-roofline shares divide them by measured time.
+roofline shares divide them by measured time.  A configuration's FLOP per
+trained token is its reference module's (``train_flops_per_token``); what
+is here is the peaks table and the counts of kernels.
 """
 from __future__ import annotations
 
@@ -21,27 +23,6 @@ def peak(device_kind: str) -> dict:
         raise KeyError(f"no peaks for device kind {device_kind!r} in "
                        f"{PEAKS.name}; known: {sorted(table)}")
     return table[device_kind]
-
-
-def matmul_params(cfg: dict) -> int:
-    """Weights that take part in a matrix product per token: attention and
-    MLP projections of every layer and the LM head.  The embedding lookup
-    is a gather, and norms and biases are not products."""
-    d, H, KV = cfg["hidden_size"], cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    hd, f = cfg["head_dim"], cfg["intermediate_size"]
-    attn = d * H * hd * 2 + d * KV * hd * 2
-    mlp = 3 * d * f
-    return cfg["num_hidden_layers"] * (attn + mlp) + d * cfg["vocab_size"]
-
-
-def train_flops_per_token(cfg: dict, seq_len: int) -> int:
-    """Forward and backward FLOP per trained token of a dense decoder:
-    6 x matmul parameters, plus causal attention counted once,
-    6 x layers x seq x heads x head_dim (QK^T and PV over on average
-    seq/2 keys, 2 FLOP a multiply-add, 3 for forward and backward)."""
-    attn = 6 * cfg["num_hidden_layers"] * seq_len * cfg["num_attention_heads"] \
-        * cfg["head_dim"]
-    return 6 * matmul_params(cfg) + attn
 
 
 def flash_attention_fwd(B: int, H: int, KV: int, S: int, hd: int, *,

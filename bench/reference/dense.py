@@ -1,7 +1,11 @@
-"""Plain reference of a dense decoder's training loss (Qwen2 / Qwen3).
+"""Plain reference of a dense decoder (Qwen2 / Qwen3): its weight layout,
+its training loss and the operations a trained token requires.
 
-Written from the architectures' published equations in straightforward
-``jax.numpy``; it imports nothing of the program under test.
+A configuration file names this module under ``reference``; the harness
+takes from it ``layout``, ``loss`` and ``train_flops_per_token``
+(``bench/spec.py``).  Written from the architectures' published equations
+in straightforward ``jax.numpy``; it imports nothing of the program under
+test.
 
     x   = E[tokens]
     per layer:
@@ -16,7 +20,7 @@ Written from the architectures' published equations in straightforward
     loss = mean over tokens of logsumexp(z) - z[label],  z = rmsnorm(x) E^T
 
 ``Wi`` is the projection the activation is applied to and ``Wg`` the one
-it is multiplied by, as the parameter layout (``params.py``) names them.
+it is multiplied by, as the parameter layout (``layout``) names them.
 
 A precision (``PRECISIONS``) is two functions: ``mm``, through which every
 matrix product goes, and ``act``, applied to the residual stream where a
@@ -37,11 +41,65 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from bench.reference.params import Layout
+
 Matmul = Callable[..., jax.Array]
 
 #: query rows per attention block and tokens per loss block
 Q_BLOCK = 512
 LOSS_BLOCK = 512
+
+
+def layout(cfg: dict) -> Layout:
+    """Leaf shapes of a dense decoder configuration (``bench/configs``)."""
+    L, d = cfg["num_hidden_layers"], cfg["hidden_size"]
+    H, KV = cfg["num_attention_heads"], cfg["num_key_value_heads"]
+    hd, f, V = cfg["head_dim"], cfg["intermediate_size"], cfg["vocab_size"]
+    out: Layout = {
+        "embed": ((V, d), "embed"),
+        "final_norm/scale": ((d,), "ones"),
+        "blocks/l0/ln1/scale": ((L, d), "ones"),
+        "blocks/l0/ln2/scale": ((L, d), "ones"),
+        "blocks/l0/attn/wq": ((L, d, H, hd), str(d)),
+        "blocks/l0/attn/wk": ((L, d, KV, hd), str(d)),
+        "blocks/l0/attn/wv": ((L, d, KV, hd), str(d)),
+        "blocks/l0/attn/wo": ((L, H, hd, d), str(H * hd)),
+        "blocks/l0/mlp/wi": ((L, d, f), str(d)),
+        "blocks/l0/mlp/wg": ((L, d, f), str(d)),
+        "blocks/l0/mlp/wo": ((L, f, d), str(f)),
+    }
+    if cfg["attention_bias"]:
+        out["blocks/l0/attn/bq"] = ((L, H, hd), "bias")
+        out["blocks/l0/attn/bk"] = ((L, KV, hd), "bias")
+        out["blocks/l0/attn/bv"] = ((L, KV, hd), "bias")
+    if cfg["qk_norm"]:
+        out["blocks/l0/attn/q_norm"] = ((L, hd), "ones")
+        out["blocks/l0/attn/k_norm"] = ((L, hd), "ones")
+    if not cfg["tie_word_embeddings"]:
+        out["lm_head"] = ((d, V), str(d))
+    return dict(sorted(out.items()))
+
+
+def matmul_params(cfg: dict) -> int:
+    """Weights that take part in a matrix product per token: attention and
+    MLP projections of every layer and the LM head.  The embedding lookup
+    is a gather, and norms and biases are not products."""
+    d, H, KV = cfg["hidden_size"], cfg["num_attention_heads"], cfg["num_key_value_heads"]
+    hd, f = cfg["head_dim"], cfg["intermediate_size"]
+    attn = d * H * hd * 2 + d * KV * hd * 2
+    mlp = 3 * d * f
+    return cfg["num_hidden_layers"] * (attn + mlp) + d * cfg["vocab_size"]
+
+
+def train_flops_per_token(cfg: dict, seq_len: int) -> int:
+    """Forward and backward FLOP per trained token: 6 x matmul parameters,
+    plus causal attention counted once, 6 x layers x seq x heads x head_dim
+    (QK^T and PV over on average seq/2 keys, 2 FLOP a multiply-add, 3 for
+    forward and backward).  What the work requires, as ``bench/flops.py``
+    counts: recomputation under remat and the masked half do not count."""
+    attn = 6 * cfg["num_hidden_layers"] * seq_len * cfg["num_attention_heads"] \
+        * cfg["head_dim"]
+    return 6 * matmul_params(cfg) + attn
 
 
 def mm_f32(spec: str, a, b) -> jax.Array:

@@ -1,12 +1,13 @@
 """Weights of a benchmark configuration, made from the run's seed.
 
 The benchmark makes the weights itself, so that the plain reference never
-takes weights that the program under test made.  The layout is the
+takes weights that the program under test made.  A layout is the
 interface the program's training step takes: one flat path per leaf
 (``blocks/l0/attn/wq``), each block leaf stacked over the layers on its
-first axis.  Both sides call :func:`init` with the same seed and get the
-same numbers: on the device, in one jitted call, in the configuration's
-parameter type.
+first axis.  Each configuration's reference module gives its own
+(``layout(cfg)``); what is here serves every layout.  Both sides call
+:func:`init` with the same seed and get the same numbers: on the device,
+in one jitted call, in the configuration's parameter type.
 
 Distributions: matrices are normal with standard deviation
 ``1/sqrt(fan_in)``, the embedding table normal with 0.02, attention biases
@@ -23,36 +24,6 @@ import jax.numpy as jnp
 #: path -> (shape, initialiser); an initialiser is "ones", "embed", "bias"
 #: or the fan-in of a matrix as a decimal string
 Layout = Dict[str, Tuple[Tuple[int, ...], str]]
-
-
-def layout(cfg: dict) -> Layout:
-    """Leaf shapes of a dense decoder configuration (``bench/configs``)."""
-    L, d = cfg["num_hidden_layers"], cfg["hidden_size"]
-    H, KV = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    hd, f, V = cfg["head_dim"], cfg["intermediate_size"], cfg["vocab_size"]
-    out: Layout = {
-        "embed": ((V, d), "embed"),
-        "final_norm/scale": ((d,), "ones"),
-        "blocks/l0/ln1/scale": ((L, d), "ones"),
-        "blocks/l0/ln2/scale": ((L, d), "ones"),
-        "blocks/l0/attn/wq": ((L, d, H, hd), str(d)),
-        "blocks/l0/attn/wk": ((L, d, KV, hd), str(d)),
-        "blocks/l0/attn/wv": ((L, d, KV, hd), str(d)),
-        "blocks/l0/attn/wo": ((L, H, hd, d), str(H * hd)),
-        "blocks/l0/mlp/wi": ((L, d, f), str(d)),
-        "blocks/l0/mlp/wg": ((L, d, f), str(d)),
-        "blocks/l0/mlp/wo": ((L, f, d), str(f)),
-    }
-    if cfg["attention_bias"]:
-        out["blocks/l0/attn/bq"] = ((L, H, hd), "bias")
-        out["blocks/l0/attn/bk"] = ((L, KV, hd), "bias")
-        out["blocks/l0/attn/bv"] = ((L, KV, hd), "bias")
-    if cfg["qk_norm"]:
-        out["blocks/l0/attn/q_norm"] = ((L, hd), "ones")
-        out["blocks/l0/attn/k_norm"] = ((L, hd), "ones")
-    if not cfg["tie_word_embeddings"]:
-        out["lm_head"] = ((d, V), str(d))
-    return dict(sorted(out.items()))
 
 
 def seed_words(seed: int) -> Tuple[jax.Array, jax.Array]:

@@ -1,6 +1,7 @@
 """Plain reference of the first training steps: loss, gradient, AdamW.
 
-Follows the configuration as it is stated: the loss of ``model.py`` in
+Follows the configuration as it is stated: the weight layout and the loss
+of the reference module that its file names (``bench.spec`` loads it) in
 float32 at ``Precision.HIGHEST``, global-norm clipping, AdamW with float32
 moments, the learning-rate schedule of the workload file, and parameters
 stored in the configuration's parameter type after every update (so a
@@ -16,13 +17,14 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
+from types import ModuleType
 from typing import Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.reference import model, params as rparams
+from bench.reference import params as rparams
 
 
 @dataclass
@@ -65,10 +67,10 @@ def lr_at(opt: dict, t: int) -> float:
     return lr * (f + (1 - f) * 0.5 * (1 + math.cos(math.pi * prog)))
 
 
-def _grads_fn(cfg: dict, precision: str):
+def _grads_fn(reference: ModuleType, cfg: dict, precision: str):
     def fn(p, tokens, labels):
         val, g = jax.value_and_grad(
-            lambda q: model.loss(cfg, precision, q, tokens, labels))(p)
+            lambda q: reference.loss(cfg, precision, q, tokens, labels))(p)
         return val, g, {k: jnp.sum(jnp.square(x)) for k, x in g.items()}
     return jax.jit(fn)
 
@@ -95,24 +97,25 @@ def change_norms(lay, lo, hi, dtype, now: Dict[str, jax.Array]) -> Dict[str, flo
     return {k: float(x) for k, x in out.items()}
 
 
-def run(cfg: dict, opt: dict, seed: int, batches: Sequence[Dict[str, np.ndarray]],
-        *, precision: str = "f32", rows: Optional[slice] = None,
-        device=None) -> Readings:
-    """``len(batches)`` steps from the seed's weights.
+def run(reference: ModuleType, cfg: dict, opt: dict, seed: int,
+        batches: Sequence[Dict[str, np.ndarray]], *, precision: str = "f32",
+        rows: Optional[slice] = None, device=None) -> Readings:
+    """``len(batches)`` steps from the seed's weights, with the ``layout``
+    and ``loss`` of the module ``reference``.
 
-    ``precision`` is one of ``model.PRECISIONS``: "f32" is the reference,
-    "fp8" the control.  ``rows`` keeps only those rows of every
+    ``precision`` is one that the module's ``loss`` takes: "f32" is the
+    reference, "fp8" the control.  ``rows`` keeps only those rows of every
     batch: the way a fault that drops part of the batch reads.
     """
     device = device or jax.devices()[0]
-    lay = rparams.layout(cfg)
+    lay = reference.layout(cfg)
     dtype = jnp.dtype(cfg["param_dtype"])
     lo, hi = rparams.seed_words(seed)
     hyper = tuple(jnp.float32(opt[k]) for k in ("b1", "b2", "eps", "weight_decay"))
     with jax.default_device(device):
         p = {k: x.astype(jnp.float32) for k, x in
              rparams.make_init(lay, dtype)(lo, hi).items()}
-        grads_fn = _grads_fn(cfg, precision)
+        grads_fn = _grads_fn(reference, cfg, precision)
         moments: Dict[str, tuple] = {}
         losses, first = [], None
         for t, b in enumerate(batches):

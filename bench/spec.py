@@ -3,12 +3,28 @@
     BENCHMARK.json                      the cells, metrics and bounds
     bench/workloads/<cell>.json         a cell: configuration, traffic, chips,
                                         mesh, optimizer, correctness limits
-    bench/configs/<config>.json         a configuration as it is run
+    bench/configs/<config>.json         a configuration as it is run; its
+                                        ``reference`` and ``program`` keys
+                                        name the two modules below
+    bench/reference/<name>.py           the plain reference of a family of
+                                        configurations: ``layout(cfg)``,
+                                        ``loss(cfg, precision, params,
+                                        tokens, labels)``,
+                                        ``train_flops_per_token(cfg, seq)``;
+                                        it imports nothing of the program
+    bench/programs/<name>.py            the program's side of it:
+                                        ``arch_of(cfg)``, the config's sizes
+                                        as the program's ``ArchConfig``,
+                                        refused where its mechanisms are not
+                                        the reference's
     bench/traffic/<traffic>.json        a traffic mix (``bench.traffic``)
     bench/metrics/<metric>.py           a per-layer metric: ``read(r)``
 
 A later cell, configuration, mix or metric is one more file and one more
-entry in ``BENCHMARK.json``; no file here changes for it.
+entry in ``BENCHMARK.json``; a model of a new family brings its reference
+and program modules as new files too.  No file here changes for any of
+them: the harness (``cell``, ``check``, ``calibrate``, ``flops``,
+``reference/train``) holds nothing of one family.
 """
 from __future__ import annotations
 
@@ -16,7 +32,8 @@ import importlib.util
 import json
 from dataclasses import dataclass
 from pathlib import Path
-from typing import Callable, List, Optional
+from types import ModuleType
+from typing import Callable, List, Optional, Sequence
 
 ROOT = Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
@@ -30,6 +47,9 @@ class Cell:
     mix: dict
     end_to_end: List[dict]
     per_layer: List[dict]
+    #: the modules the configuration names (``MODULES``)
+    reference: ModuleType
+    program: ModuleType
 
     @property
     def chips(self) -> int:
@@ -56,6 +76,38 @@ def _json(path: Path) -> dict:
     return json.loads(path.read_text())
 
 
+#: config key -> the functions the module it names must give
+MODULES = {"reference": ("layout", "loss", "train_flops_per_token"),
+           "program": ("arch_of",)}
+
+
+def module_at(path: Path, name: str, needs: Sequence[str], what: str) -> ModuleType:
+    """The module in file ``path``, which must give every function of
+    ``needs``; ``what`` names it in the error."""
+    if not path.is_file():
+        raise FileNotFoundError(f"{what}: {path} does not exist")
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    missing = [f for f in needs if not callable(getattr(mod, f, None))]
+    if missing:
+        raise AttributeError(f"{what}: {path} has no {', '.join(missing)}")
+    return mod
+
+
+def config_modules(cfg: dict, cfg_path: Path, root: Path = ROOT) -> dict:
+    """Each module of ``MODULES`` that the configuration file names, by a
+    path from ``root``."""
+    out = {}
+    for key, needs in MODULES.items():
+        if key not in cfg:
+            raise KeyError(f"{cfg_path} names no {key!r} module")
+        path = root / cfg[key]
+        out[key] = module_at(path, f"bench.{path.parent.name}.{path.stem}", needs,
+                             f"{key} of {cfg_path.name}")
+    return out
+
+
 def _applies(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
@@ -73,19 +125,16 @@ def load(name: str, root: Path = ROOT, parts: Path = BENCH) -> Cell:
         if workload[key] != entry[key]:
             raise ValueError(f"{name}: {key} is {workload[key]!r} in its file "
                              f"and {entry[key]!r} in BENCHMARK.json")
-    return Cell(name, workload,
-                _json(parts / "configs" / f"{workload['config']}.json"),
+    cfg_path = parts / "configs" / f"{workload['config']}.json"
+    cfg = _json(cfg_path)
+    return Cell(name, workload, cfg,
                 _json(parts / "traffic" / f"{workload['traffic']}.json"),
                 [m for m in index["end_to_end"] if _applies(m, name)],
-                [m for m in index["per_layer"] if _applies(m, name)])
+                [m for m in index["per_layer"] if _applies(m, name)],
+                **config_modules(cfg, cfg_path, root))
 
 
 def metric_reader(name: str, parts: Path = BENCH) -> Callable[[object], Optional[float]]:
     """``read`` of ``parts/metrics/<name>.py``."""
-    path = parts / "metrics" / f"{name}.py"
-    if not path.is_file():
-        raise FileNotFoundError(f"per-layer metric {name!r} has no reader {path}")
-    spec = importlib.util.spec_from_file_location(f"bench.metrics.{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return module_at(parts / "metrics" / f"{name}.py", f"bench.metrics.{name}",
+                     ("read",), f"reader of per-layer metric {name!r}").read

@@ -3,6 +3,7 @@ benchmark lays out its files, and the import path of ``bench``."""
 from __future__ import annotations
 
 import json
+import shutil
 import sys
 from pathlib import Path
 
@@ -20,16 +21,21 @@ TINY = dict(num_hidden_layers=2, hidden_size=64, intermediate_size=128,
 
 
 def tiny_cell(root: Path, *, name: str = "tiny", config: str = "qwen2-0.5b",
-              chips: int = 1, mesh=(1, 1, 1), batch: int = 4, seq: int = 64):
+              chips: int = 1, mesh=(1, 1, 1), batch: int = 4, seq: int = 64,
+              cfg_over: dict = None):
     """Write BENCHMARK.json and bench/{configs,traffic,workloads} for one
-    tiny cell under ``root``, at the widths of ``TINY``, and load it."""
+    tiny cell under ``root``, at the widths of ``TINY`` (and ``cfg_over``),
+    with copies of the reference and program modules, and load it."""
     from bench import spec
 
     parts = root / "bench"
     for d in ("configs", "traffic", "workloads"):
         (parts / d).mkdir(parents=True, exist_ok=True)
+    for d in ("reference", "programs"):
+        shutil.copytree(REPO / "bench" / d, parts / d, dirs_exist_ok=True,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     cfg = json.loads((REPO / "bench" / "configs" / f"{config}.json").read_text())
-    cfg.update(TINY)
+    cfg.update(TINY, **(cfg_over or {}))
     (parts / "configs" / "tiny.json").write_text(json.dumps(cfg))
     mix = json.loads((REPO / "bench" / "traffic" / "b4.s2048.json").read_text())
     mix.update(global_batch=batch, seq_len=seq)

@@ -73,8 +73,9 @@ def test_control_in_lower_precision_is_not_correct(cell):
 
     batches = bcell.reference_batches(cell, SEED)
     opt = cell.workload["optimizer"]
-    ref = rtrain.run(cell.cfg, opt, SEED, batches)
-    control = rtrain.run(cell.cfg, opt, SEED, batches, precision="fp8")
+    ref = rtrain.run(cell.reference, cell.cfg, opt, SEED, batches)
+    control = rtrain.run(cell.reference, cell.cfg, opt, SEED, batches,
+                         precision="fp8")
     ok, checks = check.judge(check.gaps(control, ref), cell.workload["limits"])
     assert not ok, checks
 

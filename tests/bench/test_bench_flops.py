@@ -1,4 +1,6 @@
-"""Required-FLOP counts and the peaks table of the benchmark."""
+"""Required-FLOP counts and the peaks table of the benchmark.  A
+configuration's FLOP per token comes from the reference module its file
+names (``bench/reference/dense.py`` for both configurations here)."""
 import json
 
 import numpy as np
@@ -13,16 +15,22 @@ def _cfg(name, **over):
     return cfg
 
 
-def test_smoke_widths_by_hand():
-    from bench import flops
+def _modules(cfg):
+    """The reference and program modules that ``cfg`` names."""
+    from bench import spec
 
+    return spec.config_modules(cfg, REPO / "bench" / "configs" / "test.json")
+
+
+def test_smoke_widths_by_hand():
     cfg = _cfg("qwen2-0.5b", **TINY)
+    ref = _modules(cfg)["reference"]
     # per layer: q and o 64*4*16 each, k and v 64*2*16 each, MLP 3*64*128;
     # two layers, then the tied LM head 64*512
     per_layer = 2 * 64 * 4 * 16 + 2 * 64 * 2 * 16 + 3 * 64 * 128
-    assert flops.matmul_params(cfg) == 2 * per_layer + 64 * 512 == 106_496
+    assert ref.matmul_params(cfg) == 2 * per_layer + 64 * 512 == 106_496
     # causal attention once: 6 * layers * seq * heads * head_dim at seq 32
-    assert flops.train_flops_per_token(cfg, 32) == 6 * 106_496 + 6 * 2 * 32 * 4 * 16
+    assert ref.train_flops_per_token(cfg, 32) == 6 * 106_496 + 6 * 2 * 32 * 4 * 16
 
 
 @pytest.mark.parametrize("name,seq,params,per_token", [
@@ -30,27 +38,26 @@ def test_smoke_widths_by_hand():
     ("qwen3-1.7b-l12", 4096, 915_144_704, 6_094_848_000),
 ])
 def test_published_widths(name, seq, params, per_token):
-    from bench import flops
-
     cfg = _cfg(name)
-    assert flops.matmul_params(cfg) == params
-    assert flops.train_flops_per_token(cfg, seq) == per_token
+    ref = _modules(cfg)["reference"]
+    assert ref.matmul_params(cfg) == params
+    assert ref.train_flops_per_token(cfg, seq) == per_token
 
 
 @pytest.mark.parametrize("name", ["qwen2-0.5b", "qwen3-1.7b-l12"])
 def test_matmul_params_match_the_models_projections(name):
     """The count equals the program's projection weights plus the tied
     head, read from its parameter shapes (at smoke widths)."""
-    from bench import flops
-    from bench.cell import arch_of
     from repro.models.registry import build_model
     from repro.utils.trees import tree_paths
 
     cfg = _cfg(name, **TINY)
-    shapes = tree_paths(build_model(arch_of(cfg)).param_shapes())
+    mods = _modules(cfg)
+    shapes = tree_paths(build_model(mods["program"].arch_of(cfg)).param_shapes())
     proj = sum(int(np.prod(s.shape)) for p, s in shapes.items()
                if p.rsplit("/", 1)[-1] in ("wq", "wk", "wv", "wo", "wi", "wg"))
-    assert flops.matmul_params(cfg) == proj + int(np.prod(shapes["embed"].shape))
+    assert mods["reference"].matmul_params(cfg) == \
+        proj + int(np.prod(shapes["embed"].shape))
 
 
 def test_peaks_by_device_kind():

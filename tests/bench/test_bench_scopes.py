@@ -1,8 +1,12 @@
 """The readers of the program's scopes and spans (``bench/scopes.py`` and
 the metrics that use it), on a trace recorded from a tiny scoped program
 on the CPU and on hand-made intervals."""
+import os
 import re
+import subprocess
+import sys
 import time
+from pathlib import Path
 
 import pytest
 
@@ -46,17 +50,16 @@ def _step():
     return jax.jit(f), jnp.ones((384, 384))
 
 
-@pytest.fixture(scope="module")
-def recorded(tmp_path_factory):
+def record(root):
     """A window of three steps as ``Trainer.train`` lays them out, under
-    the benchmark's window span; (trace dir, xplane path, compiled text)."""
+    the benchmark's window span, traced into ``root/cell``; returns (xplane
+    path, compiled text)."""
     import jax
 
     from bench import scopes, trace
 
     f, x = _step()
     f(x).block_until_ready()
-    root = tmp_path_factory.mktemp("traces")
     out = root / "cell"
     jax.profiler.start_trace(str(out))
     with jax.profiler.TraceAnnotation(trace.WINDOW_SPAN):
@@ -70,7 +73,22 @@ def recorded(tmp_path_factory):
                 with jax.profiler.TraceAnnotation("train.fetch"):
                     y.block_until_ready()
     jax.profiler.stop_trace()
-    return root, trace.find_xspace(str(out)), f.lower(x).compile().as_text()
+    return trace.find_xspace(str(out)), f.lower(x).compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def recorded(tmp_path_factory):
+    """(trace dir, xplane path, compiled text) of ``record``, run in a
+    process of its own: the XSpace holds every program its process has
+    loaded (``scopes.hlo_op_names``), and programs that earlier tests of
+    this worker loaded would share instruction names with the step's."""
+    root = tmp_path_factory.mktemp("traces")
+    proc = subprocess.run([sys.executable, __file__, str(root)],
+                          env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    return (root, proc.stdout.strip().splitlines()[-1],
+            (root / "compiled.txt").read_text())
 
 
 def _reading(t, steps=STEPS):
@@ -287,3 +305,9 @@ def test_compiler_made_instructions_take_a_neighbours_scope():
     got = scopes.module_op_names(_proto((3, entry), (3, body)))
     assert got == {"a": sync, "b": sync, "c": update, "d": update,
                    "while": sync, "f": sync, "gather": "jit(f)/gather"}
+
+
+if __name__ == "__main__":
+    path, text = record(Path(sys.argv[1]))
+    (Path(sys.argv[1]) / "compiled.txt").write_text(text)
+    print(path)

@@ -90,15 +90,13 @@ def test_each_cell_matches_the_program(name):
     parameter layout the benchmark's weights and reference use, and the
     configuration file changes from its source only what ``reduced`` says."""
     from bench import spec
-    from bench.cell import arch_of
-    from bench.reference import params as rparams
     from repro.models.registry import build_model
     from repro.utils.trees import tree_paths
 
     cell = spec.load(name["name"])
-    shapes = tree_paths(build_model(arch_of(cell.cfg)).param_shapes())
+    shapes = tree_paths(build_model(cell.program.arch_of(cell.cfg)).param_shapes())
     assert {k: tuple(v.shape) for k, v in shapes.items()} == \
-        {k: v[0] for k, v in rparams.layout(cell.cfg).items()}
+        {k: v[0] for k, v in cell.reference.layout(cell.cfg).items()}
     index = json.loads((REPO / "BENCHMARK.json").read_text())
     entry = next(c for c in index["configs"] if c["name"] == cell.workload["config"])
     assert entry["reduced"] == cell.cfg["reduced"]

@@ -226,6 +226,8 @@ class Window:
     #: host seconds of each step, from one ``batch_at`` to the next; the
     #: first from the window's start, the last to its end
     step_s: List[float]
+    #: the trainer's own ``metrics_log`` entry of each step, in order
+    counters: List[Dict[str, float]]
     trace: Optional[trace.Reduced] = None
 
 
@@ -251,6 +253,7 @@ def measure(s: Setup, params, opt, seconds: float, trace_dir: Optional[Path],
     tr.cfg = dataclasses.replace(tr.cfg, steps=s.cell.workload["optimizer"]["total_steps"])
     tr.install_preemption_handler(signals=(signal.SIGALRM,))
     done_before = len(s.pipeline.durations)
+    logged = len(tr.metrics_log)
     if trace_dir is not None:
         shutil.rmtree(trace_dir, ignore_errors=True)
         # no tracing of every Python call: it would slow the host loop that
@@ -273,7 +276,8 @@ def measure(s: Setup, params, opt, seconds: float, trace_dir: Optional[Path],
             jax.profiler.stop_trace()
     marks = [t0, *s.pipeline.starts[done_before + 1:], t1]
     w = Window(out["step"] - start, t1 - t0, counter.n,
-               s.pipeline.durations[done_before:], list(np.diff(marks)))
+               s.pipeline.durations[done_before:], list(np.diff(marks)),
+               tr.metrics_log[logged:])
     if trace_dir is not None:
         w.trace = trace.read(trace.find_xspace(str(trace_dir)))
         if w.trace.ops:
@@ -305,6 +309,10 @@ class Reading:
     mix: dict
     chips: int
     peak: dict
+    #: the program's counters: per window step, in order, the numbers the
+    #: trainer's step logged (``Trainer.metrics_log``: ``loss``,
+    #: ``grad_norm``, ...), as the trainer fetched them; no fetch of its own
+    counters: List[Dict[str, float]] = field(default_factory=list)
 
 
 def reference_batches(cell: spec.Cell, seed: int) -> List[Dict[str, np.ndarray]]:
@@ -363,7 +371,7 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool,
                    for m in cell.end_to_end if m["name"] in values}
     else:
         r = Reading(w.trace, w.steps, w.host_input_s, cell.cfg, cell.mix,
-                    cell.chips, pk)
+                    cell.chips, pk, w.counters)
         metrics = {}
         for m in cell.per_layer:
             v = spec.metric_reader(m["name"])(r)

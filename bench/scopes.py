@@ -24,6 +24,16 @@ clock: ``train`` per step (a step annotation), and inside it
 ``train.batch``, ``train.device_put``, ``train.dispatch``, ``train.fetch``
 and ``train.checkpoint``.
 
+The set of scopes is open: a reader under ``bench/metrics/`` that reads a
+scope of its own declares it at module level, ``SCOPES = ("moe",)``, a
+literal tuple of names.  ``known_scopes`` is the base tuple (``SCOPES``
+here), then every scope a reader declares, readers in the order of their
+file names; ``scope_of``, ``scope_ms`` and the ``scope_split`` line all use
+it.  Innermost wins, so ``mlp/moe/...`` is ``moe`` only where a reader
+declares ``moe``, and ``mlp`` otherwise.  A name that JAX itself puts on
+a path (``JAX_COMPONENTS``) cannot be declared: it would take every
+operation beneath it.
+
 ``bench/trace.py`` keeps neither, so the readers here read the run's
 XSpace again: the newest under ``.bench_out/trace``, taken only if its
 window is the reading's.  A program without the scopes or spans gives
@@ -36,6 +46,8 @@ prints the ``scope_split`` line of a traced benchmark window
 """
 from __future__ import annotations
 
+import ast
+import functools
 import re
 import sys
 from collections import defaultdict
@@ -50,8 +62,17 @@ from bench import trace  # noqa: E402
 
 ROOT = Path(__file__).resolve().parents[1]
 TRACES = ROOT / ".bench_out" / "trace"
+#: the readers, each of which may declare the scopes it reads
+METRICS = ROOT / "bench" / "metrics"
 
+#: the base scopes the program opens
 SCOPES = ("attention", "ssm", "mlp", "lm_loss", "grad_sync", "adamw")
+#: path components JAX puts on an op_name of its own accord
+JAX_COMPONENTS = frozenset({
+    "jit", "pjit", "jvp", "transpose", "vmap", "pmap", "while", "body", "cond",
+    "branch", "scan", "checkpoint", "remat", "rematted_computation",
+    "closed_call", "core_call", "named_call", "shard_map", "custom_jvp_call",
+    "custom_vjp_call", "custom_vjp_call_jaxpr", "pallas_call"})
 LEGS = ("reduce_scatter", "psum", "slow_chunk", "all_gather", "all_to_all")
 PHASES = ("forward", "remat", "backward")
 #: where the profiler keeps each program's HLO
@@ -75,8 +96,40 @@ def _innermost(op_name: str, known: Sequence[str]) -> Optional[str]:
     return found[-1] if found else None
 
 
+def declared(path: Path) -> Tuple[str, ...]:
+    """The scopes the reader at ``path`` declares (its module-level
+    ``SCOPES``); a name JAX puts on paths itself, or one that is no
+    identifier, is refused."""
+    for node in ast.parse(path.read_text(), str(path)).body:
+        if isinstance(node, ast.Assign) and any(
+                isinstance(t, ast.Name) and t.id == "SCOPES" for t in node.targets):
+            names = tuple(ast.literal_eval(node.value))
+            for n in names:
+                if not (isinstance(n, str) and n.isidentifier()):
+                    raise ValueError(f"{path}: scope {n!r} is not a name")
+                if n in JAX_COMPONENTS:
+                    raise ValueError(f"{path}: scope {n!r} is a component JAX "
+                                     f"puts on op_name paths itself")
+            return names
+    return ()
+
+
+@functools.lru_cache(maxsize=None)
+def _scopes_under(metrics: Path) -> Tuple[str, ...]:
+    out = list(SCOPES)
+    for path in sorted(metrics.glob("*.py")):
+        out += [n for n in declared(path) if n not in out]
+    return tuple(out)
+
+
+def known_scopes() -> Tuple[str, ...]:
+    """The base scopes, then each scope a reader under ``METRICS``
+    declares, once; read once per directory."""
+    return _scopes_under(METRICS)
+
+
 def scope_of(op_name: str) -> Optional[str]:
-    return _innermost(op_name, SCOPES)
+    return _innermost(op_name, known_scopes())
 
 
 def leg_of(op_name: str) -> Optional[str]:
@@ -309,7 +362,11 @@ def scope_ms(r, scope: str, with_async: bool = False) -> Optional[float]:
     """ms per step of the operations scoped ``scope`` on the busiest chip:
     its leaf operations, and with ``with_async`` the collectives in flight
     on its async line (not the copies in flight there, which wait beside
-    other work).  None where no operation carries the scope."""
+    other work).  None where no operation carries the scope; a scope that
+    is neither a base scope nor declared by a reader is an error."""
+    if scope not in known_scopes():
+        raise ValueError(f"scope {scope!r} is not known: declare it in its "
+                         f"reader, SCOPES = ({scope!r},)")
     t = r.trace
     if not r.steps or not t.ops:
         return None
@@ -344,7 +401,7 @@ def split_line(t: trace.Reduced, prog: Program, steps: int) -> str:
     named = trace.busy_ns([op for op in leaves if trace.short_name(op[0]) in names])
     legs = split_ns(leaves + _async_collectives(t, dev), names, leg_of)
     parts = [f"{k} {phased.get(k, 0) * ms!r}"
-             for k in (f"{sc}.{ph}" for sc in SCOPES for ph in PHASES)]
+             for k in (f"{sc}.{ph}" for sc in known_scopes() for ph in PHASES)]
     parts.append(f"unscoped_share {1 - scoped / busy if busy else 0.0!r}")
     parts.append(f"named_share {named / leaf_ns if leaf_ns else 0.0!r}")
     parts += [f"leg.{g} {legs.get(g, 0) * ms!r}" for g in LEGS]

@@ -5,7 +5,11 @@
                                         mesh, optimizer, correctness limits
     bench/configs/<config>.json         a configuration as it is run; its
                                         ``reference`` and ``program`` keys
-                                        name the two modules below
+                                        name the two modules below, and
+                                        ``flop_per_token`` (``seq_len``,
+                                        ``value``) states what its
+                                        reference's
+                                        ``train_flops_per_token`` gives
     bench/reference/<name>.py           the plain reference of a family of
                                         configurations: ``layout(cfg)``,
                                         ``loss(cfg, precision, params,
@@ -18,13 +22,20 @@
                                         refused where its mechanisms are not
                                         the reference's
     bench/traffic/<traffic>.json        a traffic mix (``bench.traffic``)
-    bench/metrics/<metric>.py           a per-layer metric: ``read(r)``
+    bench/metrics/<metric>.py           a per-layer metric: ``read(r)`` of a
+                                        ``cell.Reading`` (the window's
+                                        trace, and ``counters``, the
+                                        trainer's numbers of each step);
+                                        ``SCOPES``, where it reads program
+                                        scopes of its own (``bench.scopes``)
 
 A later cell, configuration, mix or metric is one more file and one more
 entry in ``BENCHMARK.json``; a model of a new family brings its reference
-and program modules as new files too.  No file here changes for any of
-them: the harness (``cell``, ``check``, ``calibrate``, ``flops``,
-``reference/train``) holds nothing of one family.
+and program modules as new files too, and its readers declare the scopes
+they read.  No file here or under ``tests/bench`` changes for any of them:
+the harness (``cell``, ``check``, ``calibrate``, ``flops``, ``scopes``,
+``reference/train``) and its tests hold nothing of one family or
+configuration.
 """
 from __future__ import annotations
 

@@ -20,6 +20,14 @@ TINY = dict(num_hidden_layers=2, hidden_size=64, intermediate_size=128,
             vocab_size=512)
 
 
+def configs(root: Path = REPO, reference: str = None) -> list:
+    """The ``configs`` entries of ``root/BENCHMARK.json``; with
+    ``reference``, those whose file names that reference module."""
+    entries = json.loads((root / "BENCHMARK.json").read_text())["configs"]
+    return [c for c in entries if reference is None or
+            json.loads((root / c["file"]).read_text())["reference"] == reference]
+
+
 def tiny_cell(root: Path, *, name: str = "tiny", config: str = "qwen2-0.5b",
               chips: int = 1, mesh=(1, 1, 1), batch: int = 4, seq: int = 64,
               cfg_over: dict = None):

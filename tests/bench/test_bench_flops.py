@@ -1,12 +1,15 @@
 """Required-FLOP counts and the peaks table of the benchmark.  A
 configuration's FLOP per token comes from the reference module its file
-names (``bench/reference/dense.py`` for both configurations here)."""
+names; the tests of the dense reference's count run on every configuration
+that names it."""
 import json
 
 import numpy as np
 import pytest
 
-from bench_fixtures import REPO, TINY
+from bench_fixtures import REPO, TINY, configs
+
+DENSE = configs(reference="bench/reference/dense.py")
 
 
 def _cfg(name, **over):
@@ -19,7 +22,7 @@ def _modules(cfg):
     """The reference and program modules that ``cfg`` names."""
     from bench import spec
 
-    return spec.config_modules(cfg, REPO / "bench" / "configs" / "test.json")
+    return spec.config_modules(cfg, REPO / "bench" / "configs" / "test.json", REPO)
 
 
 def test_smoke_widths_by_hand():
@@ -33,25 +36,27 @@ def test_smoke_widths_by_hand():
     assert ref.train_flops_per_token(cfg, 32) == 6 * 106_496 + 6 * 2 * 32 * 4 * 16
 
 
-@pytest.mark.parametrize("name,seq,params,per_token", [
-    ("qwen2-0.5b", 2048, 493_961_216, 3_228_008_448),
-    ("qwen3-1.7b-l12", 4096, 915_144_704, 6_094_848_000),
-])
-def test_published_widths(name, seq, params, per_token):
-    cfg = _cfg(name)
+@pytest.mark.parametrize("entry", DENSE, ids=lambda c: c["name"])
+def test_published_widths(entry):
+    """The count the file states is 6 x the matmul parameters plus causal
+    attention once, 6 x layers x seq x heads x head_dim."""
+    cfg = json.loads((REPO / entry["file"]).read_text())
     ref = _modules(cfg)["reference"]
-    assert ref.matmul_params(cfg) == params
-    assert ref.train_flops_per_token(cfg, seq) == per_token
+    seq, per_token = cfg["flop_per_token"]["seq_len"], cfg["flop_per_token"]["value"]
+    attention = 6 * cfg["num_hidden_layers"] * seq * cfg["num_attention_heads"] \
+        * cfg["head_dim"]
+    assert 6 * ref.matmul_params(cfg) + attention == per_token
 
 
-@pytest.mark.parametrize("name", ["qwen2-0.5b", "qwen3-1.7b-l12"])
-def test_matmul_params_match_the_models_projections(name):
+@pytest.mark.parametrize("entry", DENSE, ids=lambda c: c["name"])
+def test_matmul_params_match_the_models_projections(entry):
     """The count equals the program's projection weights plus the tied
     head, read from its parameter shapes (at smoke widths)."""
     from repro.models.registry import build_model
     from repro.utils.trees import tree_paths
 
-    cfg = _cfg(name, **TINY)
+    cfg = json.loads((REPO / entry["file"]).read_text())
+    cfg.update(TINY)
     mods = _modules(cfg)
     shapes = tree_paths(build_model(mods["program"].arch_of(cfg)).param_shapes())
     proj = sum(int(np.prod(s.shape)) for p, s in shapes.items()

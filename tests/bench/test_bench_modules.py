@@ -7,33 +7,30 @@ import json
 
 import pytest
 
-from bench_fixtures import REPO, tiny_cell
+from bench_fixtures import REPO, configs, tiny_cell
 
 SEED = 3_000_000_019
 
-CONFIGS = json.loads((REPO / "BENCHMARK.json").read_text())["configs"]
 
-#: required FLOP per trained token at the configuration's cell's sequence
-FLOP_PER_TOKEN = {"qwen2-0.5b": (2048, 3_228_008_448),
-                  "qwen3-1.7b-l12": (4096, 6_094_848_000)}
-
-
-@pytest.mark.parametrize("entry", CONFIGS, ids=lambda c: c["name"])
+@pytest.mark.parametrize("entry", configs(), ids=lambda c: c["name"])
 def test_named_modules_give_the_programs_layout_and_the_flop_count(entry):
-    """At published widths: the reference's layout is the program's
-    parameter layout, and its FLOP count is the number PERF.md states."""
+    """At the widths the file states: the reference's layout is the
+    program's parameter layout, and its FLOP count at the file's
+    ``flop_per_token.seq_len`` is the file's ``flop_per_token.value``."""
     from bench import spec
     from repro.models.registry import build_model
     from repro.utils.trees import tree_paths
 
     path = REPO / entry["file"]
     cfg = json.loads(path.read_text())
-    mods = spec.config_modules(cfg, path)
+    mods = spec.config_modules(cfg, path, REPO)
     shapes = tree_paths(build_model(mods["program"].arch_of(cfg)).param_shapes())
     assert {k: tuple(v.shape) for k, v in shapes.items()} == \
         {k: v[0] for k, v in mods["reference"].layout(cfg).items()}
-    seq, want = FLOP_PER_TOKEN[entry["name"]]
-    assert mods["reference"].train_flops_per_token(cfg, seq) == want
+    assert "flop_per_token" in cfg, f"{path} states no flop_per_token"
+    stated = cfg["flop_per_token"]
+    assert mods["reference"].train_flops_per_token(cfg, stated["seq_len"]) == \
+        stated["value"]
 
 
 PLANTED = '''"""The dense reference with a planted fault: every loss 5 % high."""

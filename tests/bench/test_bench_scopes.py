@@ -3,6 +3,7 @@ the metrics that use it), on a trace recorded from a tiny scoped program
 on the CPU and on hand-made intervals."""
 import os
 import re
+import shutil
 import subprocess
 import sys
 import time
@@ -196,12 +197,109 @@ def test_old_readers_see_no_program_spans(recorded):
     ("jit(step)/shard_map/grad_sync/psum", "grad_sync", None, "forward"),
     ("jit(step)/adamw/mul", "adamw", None, "forward"),
     ("jit(step)/jvp()/gather", None, None, "forward"),
+    # a scope no reader declares is no scope: its operations stay in mlp
+    ("jit(step)/jvp()/while/body/mlp/moe/dot_general", "mlp", None, "forward"),
 ])
 def test_scope_leg_and_phase_of_an_op_name(op_name, scope, leg, phase):
     from bench import scopes
 
     assert (scopes.scope_of(op_name), scopes.leg_of(op_name),
             scopes.phase_of(op_name)) == (scope, leg, phase)
+
+
+MOE_READER = '''from bench import scopes
+
+SCOPES = ("moe",)
+
+
+def read(r):
+    return scopes.scope_ms(r, "moe")
+'''
+
+
+def _declare(root, monkeypatch, text=MOE_READER, name="moe_ms"):
+    """Readers under ``root/metrics``: the repo's and one more, ``text``."""
+    from bench import scopes
+
+    shutil.copytree(scopes.METRICS, root / "metrics",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (root / "metrics" / f"{name}.py").write_text(text)
+    monkeypatch.setattr(scopes, "METRICS", root / "metrics")
+
+
+def test_a_scope_a_reader_declares(tmp_path, monkeypatch):
+    """Hand-made: a dense fusion under ``mlp`` and a grouped matmul under
+    ``mlp/moe``.  With ``moe`` declared by a reader, the grouped matmul is
+    ``moe``'s in ``scope_of``, ``scope_ms`` and ``scope_split``, and ``mlp``
+    keeps the rest; undeclared, ``mlp`` holds both and ``moe`` is unknown."""
+    from bench import scopes, spec, trace
+
+    dev = "/device:TPU:0"
+    moe_op = ("jit(step)/transpose(jvp())/while/body/closed_call/checkpoint/"
+              "rematted_computation/mlp/moe/dot_general")
+    ops = {dev: [("%fusion.1 = bf16[8] fusion()", 0, 30),
+                 ("%fusion.2 = bf16[8] fusion()", 30, 80)]}
+    t = trace.Reduced((0, 100), ops, {d: trace.leaves(v) for d, v in ops.items()})
+    prog = scopes.Program(op_names={"fusion.1": "jit(step)/jvp()/mlp/dot_general",
+                                    "fusion.2": moe_op})
+    scopes._CACHE[t.window] = prog
+    try:
+        r = _reading(t, steps=2)
+        assert spec.metric_reader("mlp_ms")(r) == pytest.approx(80 / 2 / 1e6)
+        with pytest.raises(ValueError, match="declare it"):
+            scopes.scope_ms(r, "moe")
+        _declare(tmp_path, monkeypatch)
+        assert scopes.known_scopes() == scopes.SCOPES + ("moe",)
+        assert (scopes.scope_of(moe_op), scopes.phase_of(moe_op)) == ("moe", "remat")
+        assert spec.metric_reader("mlp_ms")(r) == pytest.approx(30 / 2 / 1e6)
+        assert spec.metric_reader("moe_ms", parts=tmp_path)(r) == pytest.approx(50 / 2 / 1e6)
+        line = scopes.split_line(t, prog, 2)
+        assert f" moe.remat {50 / 2 * 1e-6!r} " in line
+        assert f" mlp.forward {30 / 2 * 1e-6!r} " in line
+    finally:
+        del scopes._CACHE[t.window]
+
+
+@pytest.mark.parametrize("name,error", [
+    ("jvp", "component JAX"), ("transpose", "component JAX"),
+    ("while", "component JAX"), ("body", "component JAX"),
+    ("checkpoint", "component JAX"), ("rematted_computation", "component JAX"),
+    ("mlp/moe", "not a name"),
+])
+def test_a_jax_path_component_cannot_be_declared(tmp_path, monkeypatch, name, error):
+    from bench import scopes
+
+    _declare(tmp_path, monkeypatch, f"SCOPES = ({name!r},)\n", name="bad")
+    with pytest.raises(ValueError, match=error):
+        scopes.known_scopes()
+
+
+def test_a_declared_scope_leaves_the_base_split_unchanged(recorded, tmp_path, monkeypatch):
+    """On the recorded window, which opens no ``moe`` scope: declaring one
+    adds its zero parts to the ``scope_split`` line and changes nothing
+    else, nor any base scope's reading."""
+    from bench import scopes, spec
+
+    root, path, _ = recorded
+    monkeypatch.setattr(scopes, "TRACES", root)
+    t = _read(path)
+
+    def readings():
+        scopes._CACHE.clear()
+        _, prog = scopes.program_of(path)
+        parts = scopes.split_line(t, prog, STEPS).split()[1:]
+        r = _reading(t)
+        return (dict(zip(parts[::2], map(float, parts[1::2]))),
+                {m: spec.metric_reader(m)(r) for m in ("attn_ms", "mlp_ms", "lm_loss_ms")})
+
+    split, read = readings()
+    _declare(tmp_path, monkeypatch)
+    declared, read_declared = readings()
+    scopes._CACHE.clear()
+    assert read_declared == read and read["mlp_ms"] > 0
+    assert {k: declared[k] for k in split} == split
+    assert {k: v for k, v in declared.items() if k not in split} == {
+        "moe.forward": 0.0, "moe.remat": 0.0, "moe.backward": 0.0}
 
 
 def test_grad_sync_counts_its_async_time_and_legs():

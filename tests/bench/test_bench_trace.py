@@ -114,3 +114,28 @@ def test_metric_readers_on_hand_made_trace():
     assert spec.metric_reader("sync_exposed_ms")(quiet) is None
     assert spec.metric_reader("flash_attention_roofline")(quiet) is None
     assert spec.metric_reader("host_input_ms")(quiet) is None
+
+
+def test_a_traced_run_hands_its_readers_the_trainers_counters(tmp_path, monkeypatch):
+    """A traced tiny run: the reading holds one counter dict per window
+    step, and each is the very entry of the trainer's ``metrics_log`` for
+    that step (no fetch of the benchmark's own), after the check steps."""
+    import dataclasses
+
+    from bench import cell as bcell
+    from bench_fixtures import tiny_cell
+
+    cell = tiny_cell(tmp_path)
+    cell = dataclasses.replace(cell, per_layer=[{"name": "counted", "unit": "1"}])
+    readings, setups = [], []
+    monkeypatch.setattr(bcell.spec, "metric_reader", lambda name: readings.append)
+    res = bcell.run(cell, 3_000_000_023, 0.3, True, time.perf_counter(),
+                    tmp_path / "out", hook=setups.append)
+    (r,), (s,) = readings, setups
+    log, n = s.trainer.metrics_log, cell.workload["check_steps"]
+    assert res["attempted"] == r.steps == len(r.counters) > 0
+    assert len(log) == n + r.steps
+    assert all(c is m for c, m in zip(r.counters, log[n:]))
+    assert [c["step"] for c in r.counters] == list(range(n, n + r.steps))
+    assert [c["loss"] for c in r.counters] == [m["loss"] for m in log[n:]]
+    assert {"loss", "grad_norm"} <= set(r.counters[0])

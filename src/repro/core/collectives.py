@@ -57,7 +57,7 @@ __all__ = [
     "SyncConfig", "dfabric_all_reduce", "dfabric_reduce_scatter",
     "dfabric_all_gather", "dfabric_all_to_all", "pod_psum",
     "lower_all_reduce", "lower_all_to_all", "lower_reduce_scatter",
-    "ring_all_reduce", "normalize_axes", "fast_axes_size",
+    "ring_all_reduce", "normalize_axes", "fast_axes_size", "slow_split",
 ]
 
 Axes = Union[str, Sequence[str]]
@@ -174,23 +174,45 @@ def _rs_leg(leg: ReduceScatter, x: jax.Array, dim: int,
                                                    cfg.make_mid_codec(), dim)
 
 
+def slow_split(legs: Sequence[SlowChunk], shape: Tuple[int, ...]) -> str:
+    """How :func:`_slow_group` cuts a run of slow chunks from an operand of
+    ``shape``: ``"rows"`` slices chunk *i* from the leading dim, which holds
+    exactly flat chunk *i* in row-major order, so no flattening relayout
+    is made; ``"flat"`` splits the flattened operand.  Rows need a
+    codec-less run (the codecs work on flat chunks, error feedback too),
+    an N-D operand and a leading dim the chunk count divides."""
+    if (len(shape) > 1 and shape[0] % len(legs) == 0
+            and all(l.codec is None for l in legs)):
+        return "rows"
+    return "flat"
+
+
 def _slow_group(legs: Sequence[SlowChunk], x: jax.Array,
                 ef: Optional[jax.Array], cfg: SyncConfig
                 ) -> Tuple[jax.Array, Optional[jax.Array]]:
-    """Sequentially lower a contiguous run of slow chunks over the
-    flattened shard (the non-pipelined slow leg).
+    """Sequentially lower a contiguous run of slow chunks over the shard
+    (the non-pipelined slow leg), cut as :func:`slow_split` says.
 
     Legs arrive in ISSUE order (sub-flow indices rotated by the
     schedule's ``lane_offset``); the payload is split and reassembled by
     ``SlowChunk.index``, so the wire order changes but the result never
-    does."""
+    does.  Both cuts give chunk *i* the same elements and the same psum,
+    so they agree bitwise."""
+    C = len(legs)
+    if slow_split(legs, x.shape) == "rows":
+        blk = x.shape[0] // C
+        outs: List = [None] * C
+        for leg in legs:
+            i = leg.index
+            outs[i], _ = _slow_chunk_psum(leg, x[i * blk:(i + 1) * blk],
+                                          None, cfg)
+        return (jnp.concatenate(outs, axis=0) if C > 1 else outs[0]), ef
     shp = x.shape
     xf = x.reshape(-1)
     ef_f = ef.reshape(-1) if ef is not None else None
-    C = len(legs)
     parts = _split_chunks(xf, C)
     ef_parts = _split_chunks(ef_f, C) if ef_f is not None else [None] * C
-    outs: List = [None] * C
+    outs = [None] * C
     nefs: List = [None] * C
     for leg in legs:
         o, ne = _slow_chunk_psum(leg, parts[leg.index], ef_parts[leg.index],

@@ -42,7 +42,8 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.core.collectives import (dfabric_all_gather, dfabric_all_reduce,
-                                    dfabric_reduce_scatter, pod_psum)
+                                    dfabric_reduce_scatter, pod_psum,
+                                    slow_split)
 from repro.utils.jax_compat import axis_size
 from repro.core.planner import Section, SyncPlan
 from repro.optim.adamw import AdamWConfig, adamw_leaf
@@ -149,6 +150,32 @@ def section_kind(sec: Section, ss: SyncSettings) -> str:
     return "full_tensor"
 
 
+def zero1_path(sec: Section, ss: SyncSettings) -> bool:
+    """The section syncs by reduce-scatter to a shard-resident ZeRO-1
+    update (a tensor shard or a scattered flat bucket)."""
+    return ss.mode == "zero1" and sec.sync.strategy == "hier_striped" \
+        and (len(sec.leaf_paths) > 1
+             or (sec.scatter_dim >= 0 and full_depth(sec, ss)))
+
+
+def slow_split_counts(plan: SyncPlan, ss: SyncSettings) -> Dict[str, int]:
+    """Slow legs of ``plan`` that the sequential slow leg cuts by rows and
+    by flat chunks (``collectives.slow_split`` on the shard each planned
+    schedule hands it), read from the plan alone.  Legs of a pipelined
+    all-reduce never reach that leg and are not counted."""
+    counts = {"rows": 0, "flat": 0}
+    for sec in plan.sections:
+        s = sec.schedule
+        if s is None or not s.slow_legs:
+            continue
+        if not zero1_path(sec, ss) and s.pipelined and s.chunks > 1:
+            continue
+        shape = list(s.shape)
+        shape[max(s.scatter_dim, 0)] //= s.scattered_prod
+        counts[slow_split(s.slow_legs, tuple(shape))] += len(s.slow_legs)
+    return counts
+
+
 def init_sync_state(plan: SyncPlan, param_shapes: Dict[str, Any],
                     ss: SyncSettings) -> Dict[str, Any]:
     """Global-shaped optimizer state: moments per Section (+EF when the
@@ -189,10 +216,9 @@ def sync_state_specs(plan: SyncPlan, param_shapes: Dict[str, Any],
 
         # moments are shard-resident on the fused ZeRO-1 paths (tensor shard
         # or scattered flat bucket)
-        zero1_path = ss.mode == "zero1" and sec.sync.strategy == "hier_striped" \
-            and (kind == "bucket" or (sec.scatter_dim >= 0 and full_depth(sec, ss)))
-        mv = shard_spec() if zero1_path else P()
-        if kind == "bucket" and zero1_path:
+        zero1 = zero1_path(sec, ss)
+        mv = shard_spec() if zero1 else P()
+        if kind == "bucket" and zero1:
             mv = P(ss.fast_entry)
         entry = {"m": mv, "v": mv}
         if init_entry_has_ef(sec):
@@ -315,8 +341,7 @@ def sync_and_update(params, grads, sync_state, plan: SyncPlan,
         entry = dict(sync_state["sections"][sec.name])
         ef = entry.get("ef")
         bucket = len(sec.leaf_paths) > 1
-        zero1_path = (ss.mode == "zero1" and sec.sync.strategy == "hier_striped"
-                      and (bucket or (sec.scatter_dim >= 0 and full_depth(sec, ss))))
+        zero1 = zero1_path(sec, ss)
         model_axes = ((ss.model_axis,) if (ss.model_axis and sec.model_sharded)
                       else ())
         # the planner's NIC-pool stagger and memory-pool staging survive
@@ -331,7 +356,7 @@ def sync_and_update(params, grads, sync_state, plan: SyncPlan,
             else:
                 g = gflat[sec.leaf_paths[0]].astype(jnp.float32)
                 k = max(sec.scatter_dim, 0)
-            if zero1_path:
+            if zero1:
                 shard, new_ef = dfabric_reduce_scatter(
                     g, ss.fast, ss.slow_axis, sec.sync, scatter_dim=k, ef=ef,
                     schedule=sec.schedule, lane_offset=lane_off, staging=staging)

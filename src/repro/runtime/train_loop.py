@@ -426,6 +426,10 @@ class Trainer:
         # cfg.metrics_path is set (see repro.obs.metrics)
         self.metrics = MetricsLogger(path=cfg.metrics_path, run="train",
                                      mode=cfg.mode)
+        if self.plan is not None:
+            split = grad_sync.slow_split_counts(self.plan, self.ss)
+            self.metrics.log("sync_plan", slow_chunks_rows=split["rows"],
+                             slow_chunks_flat=split["flat"])
 
     # ---- preemption ------------------------------------------------------------
     def install_preemption_handler(self, signals=(signal.SIGTERM,)):

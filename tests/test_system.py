@@ -23,6 +23,11 @@ def test_multi_device_collectives_battery():
     assert "ALL OK" in out
 
 
+def test_multi_device_slow_split_battery():
+    out = run_multi_device(os.path.join(HERE, "batteries", "slow_split_battery.py"))
+    assert "ALL OK" in out
+
+
 def test_multi_device_train_battery():
     out = run_multi_device(os.path.join(HERE, "batteries", "train_battery.py"),
                            timeout=900)

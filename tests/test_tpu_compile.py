@@ -181,6 +181,16 @@ def test_one_chip_train_step_carries_scopes(one_chip_step, scope, phases):
     assert not [o for o in names if has(o, "grad_sync") and has(o, "adamw")]
 
 
+def test_dp4_train_step_relays_no_slow_chunks(dp4_step):
+    """The slow leg cuts each ZeRO-1 shard's chunks from its leading rows:
+    the compiled four-chip step copies no shard, chunk by chunk, into an
+    ``f32[1, chunks, n]`` buffer."""
+    relayed = [ln for ln in dp4_step.as_text().splitlines()
+               if "dynamic-update-slice" in ln
+               and re.match(r"\s*(ROOT )?%\S+ = f32\[1,\d+,\d+\]", ln)]
+    assert not relayed, relayed[:3]
+
+
 @pytest.mark.parametrize("leg,op", [("reduce_scatter", "reduce-scatter"),
                                     ("slow_chunk", "all-reduce"),
                                     ("all_gather", "all-gather")])

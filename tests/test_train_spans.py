@@ -1,7 +1,7 @@
 """The training loop's own instrumentation: a step annotation and host
 spans per step on the profiler's clock, the operator's ``profile_dir`` /
-``profile_steps`` window, and recompiles reported at the step that made
-them."""
+``profile_steps`` window, recompiles reported at the step that made
+them, and the plan's ``sync_plan`` record."""
 import glob
 import os
 
@@ -114,6 +114,15 @@ def test_one_compile_listener_per_process():
     for _ in range(2):
         _trainer(1).train()
     assert monitoring.get_event_duration_listeners().count(train_loop._count_compile) == 1
+
+
+def test_sync_plan_record_on_one_chip():
+    """The trainer logs how its plan's slow legs are cut, once, at build;
+    one chip has no ``pod`` axis and so no slow leg."""
+    tr = _trainer(1)
+    rec = [(r["slow_chunks_rows"], r["slow_chunks_flat"])
+           for r in tr.metrics.records if r["event"] == "sync_plan"]
+    assert rec == [(0, 0)]
 
 
 @pytest.mark.parametrize("text,want", [("5:8", (5, 8)), ("0:1", (0, 1)),

@@ -3,8 +3,7 @@ collectives (the schedule executor), planner, NIC-pool and memory-pool
 arbiters."""
 from repro.core.topology import (
     FabricSpec, HardwareSpec, Tier, TwoTierTopology, as_fabric,
-    fabric_from_mesh_sizes, production_topology, three_tier_fabric,
-    topology_from_mesh_sizes)
+    fabric_from_mesh_sizes, production_topology, three_tier_fabric)
 from repro.core.nicpool import LaneGrant, LaneRequest, NicPool, waterfill
 from repro.core.mempool import (
     MemDevice, MemGrant, MemPool, MemPoolSpec, MemRequest, mem_waterfill)
@@ -18,13 +17,12 @@ from repro.core.cost_model import (
 from repro.core.collectives import (
     dfabric_all_gather, dfabric_all_reduce, dfabric_all_to_all,
     dfabric_reduce_scatter, lower_all_reduce, lower_all_to_all,
-    lower_reduce_scatter, pod_psum, ring_all_reduce)
+    lower_reduce_scatter, ring_all_reduce)
 from repro.core.planner import Planner, SyncPlan, Section
 
 __all__ = [
     "FabricSpec", "HardwareSpec", "Tier", "TwoTierTopology", "as_fabric",
     "fabric_from_mesh_sizes", "production_topology", "three_tier_fabric",
-    "topology_from_mesh_sizes",
     "LaneGrant", "LaneRequest", "NicPool", "waterfill",
     "MemDevice", "MemGrant", "MemPool", "MemPoolSpec", "MemRequest",
     "mem_waterfill",
@@ -35,6 +33,6 @@ __all__ = [
     "ScheduleEstimate", "TierCharge",
     "dfabric_all_gather", "dfabric_all_reduce", "dfabric_all_to_all",
     "dfabric_reduce_scatter", "lower_all_reduce", "lower_all_to_all",
-    "lower_reduce_scatter", "pod_psum", "ring_all_reduce",
+    "lower_reduce_scatter", "ring_all_reduce",
     "Planner", "SyncPlan", "Section",
 ]

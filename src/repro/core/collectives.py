@@ -25,12 +25,15 @@ builds a typed leg list (``ReduceScatter`` / ``Psum`` / ``SlowChunk`` /
 Codec / chunking (``SyncConfig``) apply to the slowest leg — DFabric's
 point is that bandwidth is scarce exactly there; an optional ``mid_codec``
 compresses mid-tier legs — unscattered psums AND scattered reduce-scatter
-legs (fastest active tier stays exact) — in deep hierarchies.  The legacy
-entry points (``dfabric_all_reduce`` / ``dfabric_reduce_scatter``, and
-``dfabric_all_to_all`` for ``kind="all_to_all"`` schedules — shuffle / MoE
-dispatch traffic) survive as thin constructors: given no schedule they
-build one in-trace from ``(axes, SyncConfig, shape)`` via the same builder
-the planner uses.
+legs (fastest active tier stays exact) — in deep hierarchies.
+
+The entry points (``dfabric_all_reduce`` / ``dfabric_reduce_scatter``,
+and ``dfabric_all_to_all`` for ``kind="all_to_all"`` schedules — shuffle /
+MoE dispatch traffic) lower a given ``schedule`` exactly as planned: one
+whose shape or mesh axes do not fit the operand is a ``ValueError``,
+never rebuilt.  Called without a schedule (direct callers), they build
+one in-trace from ``(axes, SyncConfig, shape)`` and the live axis sizes,
+via the same builder the planner uses.
 
 Each leg lowers inside a ``jax.named_scope`` named for its kind
 (``reduce_scatter``, ``psum``, ``slow_chunk``, ``all_gather``,
@@ -47,17 +50,16 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core import compression as comp
-from repro.core.schedule import (AllGather, AllToAll, CommSchedule, Psum,
+from repro.core.schedule import (AllToAll, CommSchedule, Psum,
                                  ReduceScatter, SlowChunk, SyncConfig,
-                                 all_to_all_from_axes, build_schedule,
-                                 schedule_from_axes)
+                                 all_to_all_from_axes, schedule_from_axes)
 from repro.utils.jax_compat import axis_size
 
 __all__ = [
     "SyncConfig", "dfabric_all_reduce", "dfabric_reduce_scatter",
-    "dfabric_all_gather", "dfabric_all_to_all", "pod_psum",
+    "dfabric_all_gather", "dfabric_all_to_all",
     "lower_all_reduce", "lower_all_to_all", "lower_reduce_scatter",
-    "ring_all_reduce", "normalize_axes", "fast_axes_size", "slow_split",
+    "ring_all_reduce", "normalize_axes", "slow_split",
 ]
 
 Axes = Union[str, Sequence[str]]
@@ -77,13 +79,6 @@ def normalize_axes(fast_axis: Optional[Axes]) -> Tuple[str, ...]:
     return tuple(fast_axis)
 
 
-def fast_axes_size(fast_axis: Optional[Axes]) -> int:
-    n = 1
-    for a in normalize_axes(fast_axis):
-        n *= axis_size(a)
-    return n
-
-
 def _split_chunks(x: jax.Array, chunks: int) -> Sequence[jax.Array]:
     if chunks <= 1:
         return [x]
@@ -94,35 +89,31 @@ def _split_chunks(x: jax.Array, chunks: int) -> Sequence[jax.Array]:
 
 def _trace_schedule(fast: Tuple[str, ...], slow_axis: Optional[str],
                     cfg: SyncConfig, shape: Tuple[int, ...],
-                    scatter_dim: int, lane_offset: int = 0,
-                    staging: Optional[str] = None) -> CommSchedule:
-    """Build a schedule in-trace from live axis sizes (the legacy entry
-    points' constructor path).  ``lane_offset`` preserves the planner's
-    NIC-pool stagger and ``staging`` its memory-pool placement when the
-    planned schedule had to be rebuilt."""
+                    scatter_dim: int) -> CommSchedule:
+    """Build a schedule in-trace from live axis sizes (the entry points'
+    path when called without a schedule)."""
+    return schedule_from_axes(fast, slow_axis, cfg, shape, scatter_dim,
+                              _live_sizes(fast, slow_axis))
+
+
+def _live_sizes(fast: Tuple[str, ...], slow_axis: Optional[str]):
     sizes = {a: axis_size(a) for a in fast}
     if slow_axis is not None:
         sizes[slow_axis] = axis_size(slow_axis)
-    s = schedule_from_axes(fast, slow_axis, cfg, shape, scatter_dim, sizes)
-    if lane_offset:
-        s = s.with_lane_offset(lane_offset)
-    if staging is not None:
-        s = s.with_staging(staging)
-    return s
+    return sizes
 
 
-def _schedule_usable(schedule: Optional[CommSchedule], x: jax.Array,
-                     fast: Tuple[str, ...], slow_axis: Optional[str]) -> bool:
-    """A planner-built schedule is trusted only when it describes exactly
-    this operand (shape) and these mesh axes; otherwise the executor
-    rebuilds in-trace (e.g. the non-nested TP path sees model-global
-    shapes the planner never planned for)."""
-    if schedule is None:
-        return False
-    if tuple(schedule.shape) != tuple(x.shape):
-        return False
+def _check_fits(schedule: CommSchedule, x: jax.Array,
+                fast: Tuple[str, ...], slow_axis: Optional[str]) -> None:
+    """A planned schedule is lowered only on the operand (shape) and the
+    mesh axes it was planned for."""
     avail = set(fast) | ({slow_axis} if slow_axis else set())
-    return set(schedule.axes) <= avail
+    if tuple(schedule.shape) != tuple(x.shape) \
+            or not set(schedule.axes) <= avail:
+        raise ValueError(
+            f"schedule planned for shape {tuple(schedule.shape)} on axes "
+            f"{schedule.axes} does not fit the operand of shape "
+            f"{tuple(x.shape)} on axes {tuple(fast)} + slow {slow_axis!r}")
 
 
 # ---------------------------------------------------------------------------
@@ -391,36 +382,17 @@ def lower_reduce_scatter(schedule: CommSchedule, x: jax.Array,
     """Lower only the down half of a schedule (fast-tier reduce-scatters +
     slow leg), leaving the caller owning its 1/prod(fast sizes) shard —
     the ZeRO-1 entry point (the up legs later carry updated parameters)."""
-    assert schedule.strategy == "hier_striped", schedule.strategy
-    assert not any(isinstance(l, Psum) for l in schedule.down_legs), \
-        "ZeRO-1 sections must scatter every fast tier"
+    if schedule.strategy != "hier_striped" \
+            or any(isinstance(l, Psum) for l in schedule.down_legs):
+        raise ValueError(
+            f"a reduce-scatter needs a striped schedule that scatters every "
+            f"fast tier: {schedule.describe()}")
     return _lower_sequential(schedule, x, ef, leg_log, gather_up=False)
 
 
 # ---------------------------------------------------------------------------
-# Legacy entry points — thin constructors over the IR
+# Entry points: lower the planned schedule, or build one from live sizes
 # ---------------------------------------------------------------------------
-
-
-def pod_psum(x: jax.Array, slow_axis: Optional[str], cfg: SyncConfig,
-             ef: Optional[jax.Array] = None,
-             lane_offset: int = 0
-             ) -> Tuple[jax.Array, Optional[jax.Array]]:
-    """All-reduce ``x`` (this chip's fast-tier-scattered shard) over the
-    slowest axis — the bare NIC-pool leg, kept for direct callers.
-
-    ``cfg.chunks`` splits the transfer into independent sub-flows; the
-    codec (if any) runs here and only here.  ``lane_offset`` rotates the
-    sub-flow issue order (the NIC-pool stagger)."""
-    if slow_axis is None or axis_size(slow_axis) == 1:
-        return x, ef
-    n = axis_size(slow_axis)
-    chunks = max(cfg.chunks, 1) if cfg.codec != "topk" else 1
-    while chunks > 1 and x.shape[0] % chunks != 0:
-        chunks -= 1
-    legs = [SlowChunk((j + lane_offset) % chunks, chunks, cfg.codec,
-                      slow_axis, slow_axis, n) for j in range(chunks)]
-    return _slow_group(legs, x, ef, cfg)
 
 
 def dfabric_all_reduce(x: jax.Array, fast_axis: Optional[Axes],
@@ -429,25 +401,20 @@ def dfabric_all_reduce(x: jax.Array, fast_axis: Optional[Axes],
                        ef: Optional[jax.Array] = None,
                        schedule: Optional[CommSchedule] = None,
                        leg_log: Optional[List] = None,
-                       lane_offset: int = 0,
-                       staging: Optional[str] = None,
                        ) -> Tuple[jax.Array, Optional[jax.Array]]:
     """All-reduce ``x`` over (fast tiers x slow tier) with the DFabric plan.
 
     ``fast_axis``: one axis name or an ordered sequence (fastest first).
-    ``x`` may be any rank; ``scatter_dim`` is the dimension scattered over
-    the fast tiers (must be divisible by the product of the scattered tier
-    sizes — indivisible tensors fall back to a flat psum).  When the
-    planner already built a :class:`CommSchedule` for this Section, pass
-    it via ``schedule``; otherwise one is built in-trace from ``cfg``
-    (``lane_offset`` keeps the planner's NIC-pool stagger and ``staging``
-    its memory-pool placement on that path — staging is an annotation
-    here: the lowering is placement-free on this backend, but the rebuilt
-    schedule must round-trip what the planner chose)."""
+    ``schedule`` is the planner's :class:`CommSchedule` for this operand,
+    lowered as it is.  Without one, a schedule is built in-trace from
+    ``cfg``: ``x`` may be any rank, and ``scatter_dim`` is the dimension
+    scattered over the fast tiers (indivisible tensors fall back to a
+    flat psum)."""
     fast = normalize_axes(fast_axis)
-    if not _schedule_usable(schedule, x, fast, slow_axis):
-        schedule = _trace_schedule(fast, slow_axis, cfg, x.shape, scatter_dim,
-                                   lane_offset, staging)
+    if schedule is None:
+        schedule = _trace_schedule(fast, slow_axis, cfg, x.shape, scatter_dim)
+    else:
+        _check_fits(schedule, x, fast, slow_axis)
     return lower_all_reduce(schedule, x, ef=ef, leg_log=leg_log)
 
 
@@ -456,21 +423,22 @@ def dfabric_reduce_scatter(x: jax.Array, fast_axis: Axes,
                            cfg: SyncConfig, scatter_dim: int = 0,
                            ef: Optional[jax.Array] = None,
                            schedule: Optional[CommSchedule] = None,
-                           leg_log: Optional[List] = None,
-                           lane_offset: int = 0,
-                           staging: Optional[str] = None):
+                           leg_log: Optional[List] = None):
     """Like :func:`dfabric_all_reduce` but stops before the final fast-tier
-    all-gathers — the caller owns the 1/prod(fast sizes) shard, indexed
-    fastest-tier-major (ZeRO-1 entry point)."""
+    all-gathers — the caller owns the 1/prod(fast sizes) shard of
+    ``scatter_dim``, indexed fastest-tier-major (ZeRO-1 entry point).  A
+    schedule built in-trace scatters every fast tier."""
     fast = normalize_axes(fast_axis)
-    nf = fast_axes_size(fast)
-    assert x.shape[scatter_dim] % nf == 0, (x.shape, scatter_dim, nf)
-    if not _schedule_usable(schedule, x, fast, slow_axis) \
-            or schedule.strategy != "hier_striped" \
-            or any(isinstance(l, Psum) for l in schedule.down_legs):
-        full = _dc_replace(cfg, scatter_depth=-1)
-        schedule = _trace_schedule(fast, slow_axis, full, x.shape,
-                                   scatter_dim, lane_offset, staging)
+    if schedule is None:
+        schedule = _trace_schedule(fast, slow_axis,
+                                   _dc_replace(cfg, scatter_depth=-1),
+                                   x.shape, scatter_dim)
+    else:
+        _check_fits(schedule, x, fast, slow_axis)
+        if schedule.scatter_dim != scatter_dim:
+            raise ValueError(
+                f"schedule scatters dim {schedule.scatter_dim}, the caller "
+                f"owns a shard of dim {scatter_dim}")
     return lower_reduce_scatter(schedule, x, ef=ef, leg_log=leg_log)
 
 
@@ -567,36 +535,26 @@ def dfabric_all_to_all(x: jax.Array, fast_axis: Axes,
                        slow_axis: Optional[str],
                        cfg: Optional[SyncConfig] = None,
                        schedule: Optional[CommSchedule] = None,
-                       leg_log: Optional[List] = None,
-                       lane_offset: int = 0,
-                       staging: Optional[str] = None) -> jax.Array:
+                       leg_log: Optional[List] = None) -> jax.Array:
     """All-to-all over the (fast tiers x slow tier) DP domain, one stage
-    per tier — the thin in-trace constructor over
-    :func:`lower_all_to_all` (see its docstring for the payload layout
-    and numerics contract).
+    per tier — the entry point over :func:`lower_all_to_all` (see its
+    docstring for the payload layout and numerics contract).
 
-    When the planner already built a ``kind="all_to_all"``
-    :class:`CommSchedule` for this exchange (``Planner.plan_all_to_all``),
-    pass it via ``schedule``; otherwise one is built in-trace from
-    ``cfg`` (default: one slow sub-flow) and the live axis sizes —
-    ``lane_offset`` keeps the planner's NIC-pool stagger and ``staging``
-    its memory-pool placement on that path, exactly like
-    :func:`dfabric_all_reduce`."""
-    if schedule is not None and schedule.kind != "all_to_all":
+    A ``kind="all_to_all"`` :class:`CommSchedule` from the planner
+    (``Planner.plan_all_to_all``) is lowered as it is, like
+    :func:`dfabric_all_reduce`'s; without one, a schedule is built
+    in-trace from ``cfg`` (default: one slow sub-flow) and the live axis
+    sizes."""
+    fast = normalize_axes(fast_axis)
+    if schedule is None:
+        schedule = all_to_all_from_axes(fast, slow_axis, cfg or SyncConfig(),
+                                        x.shape, _live_sizes(fast, slow_axis))
+    elif schedule.kind != "all_to_all":
         raise ValueError(
             f"dfabric_all_to_all needs an all_to_all schedule, got "
             f"kind={schedule.kind!r}")
-    fast = normalize_axes(fast_axis)
-    if not _schedule_usable(schedule, x, fast, slow_axis):
-        cfg = cfg or SyncConfig()
-        sizes = {a: axis_size(a) for a in fast}
-        if slow_axis is not None:
-            sizes[slow_axis] = axis_size(slow_axis)
-        schedule = all_to_all_from_axes(fast, slow_axis, cfg, x.shape, sizes)
-        if lane_offset:
-            schedule = schedule.with_lane_offset(lane_offset)
-        if staging is not None:
-            schedule = schedule.with_staging(staging)
+    else:
+        _check_fits(schedule, x, fast, slow_axis)
     return lower_all_to_all(schedule, x, leg_log=leg_log)
 
 

@@ -18,10 +18,11 @@ planning at trace time:
   * the plan is a static artifact — inspectable, serializable, and testable
     without running anything.
 
-The planner accepts either the legacy :class:`TwoTierTopology` or an
-N-tier :class:`FabricSpec`; with more than two tiers the per-section search
-runs over scatter depths of the hierarchical collective (see
-``repro.core.schedule``).
+The planner accepts either a :class:`TwoTierTopology` or an N-tier
+:class:`FabricSpec`, and plans on the mesh's own fast DP axes
+(``Planner(fast_axes=...)``); with more than one fast tier the
+per-section search runs over scatter depths of the hierarchical
+collective (see ``repro.core.schedule``).
 """
 from __future__ import annotations
 
@@ -54,10 +55,10 @@ class Section:
     shapes it sees are per-model-shard.  ``model_sharded`` marks sections
     whose gradient is split over the TP axis (their global sq-norm needs an
     extra psum over 'model').  The tier plan lives in ``schedule`` (the
-    planner-built :class:`CommSchedule` the executor lowers); ``sync``
-    keeps the equivalent :class:`SyncConfig` knobs for legacy consumers
-    and for rebuilding the schedule in-trace when shapes differ (the
-    non-nested TP path)."""
+    planner-built :class:`CommSchedule` the executor lowers as it is);
+    ``sync`` keeps the :class:`SyncConfig` knobs it was built from, which
+    ``grad_sync`` reads to pick the section's path (shard, bucket, whole
+    tensor) and its codec state."""
 
     name: str
     leaf_paths: Tuple[str, ...]
@@ -172,13 +173,14 @@ class SyncPlan:
 class Planner:
     """Plans one :class:`SyncPlan` for a gradient pytree.
 
-    ``topo``: TwoTierTopology | FabricSpec.  ``fast_axis_sizes`` overrides
-    the per-tier fast-axis extents (ordered fastest first) when the mesh
-    truth differs from the fabric description; ``fast_axis_size`` is the
-    legacy single-tier override.  ``pipeline`` enables the overlapped
-    slow-leg pipeline for chunked sections; ``mid_codec`` adds candidates
-    that int8-compress mid-tier legs (unscattered psums AND scattered
-    reduce-scatters — the fastest active tier stays exact);
+    ``topo``: TwoTierTopology | FabricSpec.  ``fast_axes`` is the mesh's
+    fast DP axes as ordered ``(name, size)`` pairs, fastest first: every
+    schedule's fast legs run on exactly those axes, at those sizes (what
+    the executor lowers), while the fabric prices each leg by its tier.
+    None plans on the fabric's own fast tiers.  ``pipeline`` enables the
+    overlapped slow-leg pipeline for chunked sections; ``mid_codec`` adds
+    candidates that int8-compress mid-tier legs (unscattered psums AND
+    scattered reduce-scatters — the fastest active tier stays exact);
     ``stagger_lanes`` asks the NIC-pool arbiter for per-Section sub-flow
     phase offsets (``CommSchedule.lane_offset``) so concurrent Sections'
     slow legs interleave across pool lanes instead of colliding.
@@ -202,8 +204,7 @@ class Planner:
     hide)."""
 
     def __init__(self, topo: Union[TwoTierTopology, FabricSpec], *,
-                 fast_axis_size: Optional[int] = None,
-                 fast_axis_sizes: Optional[Sequence[int]] = None,
+                 fast_axes: Optional[Sequence[Tuple[str, int]]] = None,
                  codec: Optional[str] = None,
                  max_chunks: int = 8,
                  min_chunk_numel: int = 1 << 16,
@@ -217,17 +218,8 @@ class Planner:
         self.cost = CostModel(topo)
         self.stagger_lanes = stagger_lanes
         self.nic_pool = NicPool.from_fabric(self.fabric)
-        # remembered so for_fabric() can tell a mesh-truth override apart
-        # from fabric-derived defaults (even when they happen to coincide)
-        self._explicit_fast_sizes = (fast_axis_sizes is not None
-                                     or fast_axis_size is not None)
-        if fast_axis_sizes is not None:
-            self.fast_sizes: Tuple[int, ...] = tuple(int(s) for s in fast_axis_sizes)
-        elif fast_axis_size is not None:
-            self.fast_sizes = (int(fast_axis_size),)
-        else:
-            self.fast_sizes = tuple(t.size for t in self.fabric.fast_tiers) or (1,)
-        self.nf = int(np.prod(self.fast_sizes))
+        self.mesh_axes = (None if fast_axes is None else
+                          tuple((str(a), int(n)) for a, n in fast_axes))
         self.codec = codec
         self.max_chunks = max_chunks
         self.min_chunk_numel = min_chunk_numel
@@ -241,14 +233,12 @@ class Planner:
     def for_fabric(self, topo: Union[TwoTierTopology, FabricSpec]
                    ) -> "Planner":
         """A new planner with THIS planner's knobs on a different fabric
-        (typically ``FabricSpec.degrade(...)``'s output).  A
-        ``fast_axis_sizes`` mesh override carries over verbatim; when the
-        sizes were just the old fabric's defaults, the new planner
-        re-derives them from the new fabric instead — a degraded tier
-        (``tier_members``) then shrinks the plan's fast axes too."""
-        sizes = self.fast_sizes if self._explicit_fast_sizes else None
+        (typically ``FabricSpec.degrade(...)``'s output).  The mesh's
+        ``fast_axes`` carry over verbatim; a planner without them plans on
+        the new fabric's fast tiers, so a degraded tier
+        (``tier_members``) shrinks the plan's fast axes too."""
         return Planner(topo,
-                       fast_axis_sizes=sizes,
+                       fast_axes=self.mesh_axes,
                        codec=self.codec,
                        max_chunks=self.max_chunks,
                        min_chunk_numel=self.min_chunk_numel,
@@ -276,15 +266,33 @@ class Planner:
         return new_plan, diff_plans(old_plan, new_plan, reason=reason)
 
     @property
+    def fast_axes(self) -> Tuple[Tuple[str, int], ...]:
+        """The ``(name, size)`` fast axes every schedule runs on.  A
+        one-tier fabric has no fast tier: its own axis stands in at size
+        1, which gives no leg."""
+        if self.mesh_axes is not None:
+            return self.mesh_axes
+        fast = tuple((t.axis, t.size) for t in self.fabric.fast_tiers)
+        return fast or ((self.fabric.tiers[0].axis, 1),)
+
+    @property
+    def fast_sizes(self) -> Tuple[int, ...]:
+        return tuple(n for _, n in self.fast_axes)
+
+    @property
+    def nf(self) -> int:
+        return int(np.prod(self.fast_sizes))
+
+    @property
     def n_fast_tiers(self) -> int:
-        return len(self.fast_sizes)
+        return len(self.fast_axes)
 
     @property
     def domain_size(self) -> int:
         """Member count of the DP domain THIS planner plans for: the
-        product of the ACTIVE (size > 1) fast-tier extents — honoring the
-        ``fast_axis_sizes`` mesh override — times the slow tier's.  This
-        is the row count ``plan_all_to_all`` payloads must carry."""
+        product of the ACTIVE (size > 1) ``fast_axes`` extents times the
+        slow tier's.  This is the row count ``plan_all_to_all`` payloads
+        must carry."""
         n = int(np.prod([s for s in self.fast_sizes if s > 1])) \
             if any(s > 1 for s in self.fast_sizes) else 1
         if self.fabric.depth > 1 and self.fabric.slowest.size > 1:
@@ -393,7 +401,7 @@ class Planner:
     def _build(self, cfg: SyncConfig, shape: Tuple[int, ...], sd: int,
                dtype: str) -> CommSchedule:
         return build_schedule(self.fabric, cfg, shape, max(sd, 0),
-                              dtype=dtype, fast_sizes=self.fast_sizes)
+                              dtype=dtype, fast_axes=self.fast_axes)
 
     @staticmethod
     def _knobs(cfg: SyncConfig, s: Optional[CommSchedule]) -> dict:
@@ -420,12 +428,15 @@ class Planner:
 
     def _search_section(self, lshape: Tuple[int, ...],
                         avoid: frozenset = frozenset(),
-                        report_name: Optional[str] = None
+                        report_name: Optional[str] = None,
+                        full_depth: bool = False
                         ) -> Tuple[SyncConfig, int, Optional[CommSchedule]]:
         """Search candidate schedules (depth x chunks x per-tier codec x
         slow-leg path split), pricing each with
         ``CostModel.from_schedule``; returns the winner's
-        (SyncConfig, scatter_dim, CommSchedule).
+        (SyncConfig, scatter_dim, CommSchedule).  ``full_depth`` prices
+        striped candidates at the deepest scatter only (a bucket, which
+        ``grad_sync`` always scatters to a 1/n_fast shard).
 
         Schedules are priced at the fp32 WIRE dtype (grad_sync upcasts
         every gradient before the collectives run); feasibility (scatter
@@ -461,7 +472,8 @@ class Planner:
 
         cands: List[Tuple[float, SyncConfig, CommSchedule]] = []
         if strat in ("auto", "hier_striped"):
-            for d in range(dmax, 0, -1):  # deepest first
+            last = dmax - 1 if full_depth else 0
+            for d in range(dmax, last, -1):  # deepest first
                 depth_val = -1 if d >= self.n_fast_tiers else d
                 shard_numel = numel // self._prefix_prod(d)
                 mids: List[Optional[str]] = [None]
@@ -556,7 +568,7 @@ class Planner:
             probe = build_all_to_all(
                 fab, SyncConfig(strategy="hier_striped", chunks=1,
                                 pipeline=False),
-                shape, dtype, fast_sizes=self.fast_sizes,
+                shape, dtype, fast_axes=self.fast_axes,
                 dest_sizes=dest_sizes)
             slow = probe.slow_legs
             if slow and slow[0].dest_sizes:
@@ -570,7 +582,7 @@ class Planner:
                 cfg = SyncConfig(strategy="hier_striped", chunks=c,
                                  pipeline=False, path_split=split)
                 s0 = build_all_to_all(fab, cfg, shape, dtype,
-                                      fast_sizes=self.fast_sizes,
+                                      fast_axes=self.fast_axes,
                                       dest_sizes=dest_sizes)
                 for stg in self._staging_candidates():
                     s = s0.with_staging(stg)
@@ -678,15 +690,7 @@ class Planner:
             cfg, _, sched = self._search_section(
                 (padded,),
                 report_name=(f"bucket[{bucket[0][0].replace('/', '.')}"
-                             f"...x{len(bucket)}]"))
-            depth = self.n_fast_tiers if cfg.scatter_depth < 0 \
-                else cfg.scatter_depth
-            chunks = self._adjust_chunks((padded,), 0, cfg.chunks, depth)
-            if chunks != cfg.chunks:
-                stg = sched.staging if sched is not None else None
-                cfg = replace(cfg, chunks=chunks)
-                sched = self._build(cfg, (padded,), 0,
-                                    "float32").with_staging(stg)
+                             f"...x{len(bucket)}]"), full_depth=True)
             sections.append(Section(
                 name=f"bucket[{bucket[0][0].replace('/', '.')}...x{len(bucket)}]",
                 leaf_paths=tuple(p for p, _ in bucket), numel=numel,
@@ -730,15 +734,3 @@ class Planner:
                               schedule=sec.schedule.with_lane_offset(off))
             out.append(sec)
         return out
-
-    def _adjust_chunks(self, shape, scatter_dim, chunks, depth=None) -> int:
-        """Chunking flattens the fast-tier-scattered shard; ensure
-        divisibility of the shard the slow leg actually sees."""
-        if scatter_dim < 0:
-            return 1
-        nf = self._prefix_prod(depth) if depth is not None else self.nf
-        numel = int(np.prod(shape)) // max(nf, 1)
-        c = min(chunks, self.max_chunks)
-        while c > 1 and numel % c != 0:
-            c -= 1
-        return c

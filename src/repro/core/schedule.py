@@ -37,9 +37,9 @@ Three consumers walk the SAME leg list:
 The builder owns ALL divisibility decisions (which tiers scatter, how many
 chunks survive), so the executor and the cost model never re-derive them.
 
-``SyncConfig`` lives here (re-exported from ``repro.core.collectives`` for
-the legacy import path) and the legacy entry points are thin constructors
-over :func:`build_schedule`.
+``SyncConfig`` lives here (re-exported from ``repro.core.collectives``).
+The planner builds every schedule the train step runs; the collectives'
+entry points build one in-trace only when called without a schedule.
 """
 from __future__ import annotations
 
@@ -567,9 +567,10 @@ def schedule_from_axes(fast_axes: Sequence[str], slow_axis: Optional[str],
     """Build a :class:`CommSchedule` from raw axis names + sizes.
 
     This is the generic core: :func:`build_schedule` feeds it a
-    ``FabricSpec``, and the legacy in-trace entry points feed it
-    ``lax.axis_size`` results.  ``tier_names`` maps axis -> tier name for
-    display/pricing (defaults to the axis name itself)."""
+    ``FabricSpec``, and the collectives' entry points, called without a
+    schedule, feed it ``lax.axis_size`` results.  ``tier_names`` maps
+    axis -> tier name for display/pricing (defaults to the axis name
+    itself)."""
     if cfg.mid_codec not in (None, "int8"):
         raise ValueError(
             f"mid_codec={cfg.mid_codec!r}: only int8 is supported on "
@@ -675,39 +676,35 @@ def schedule_from_axes(fast_axes: Sequence[str], slow_axis: Optional[str],
                         "hier_striped", cfg)
 
 
-def build_schedule(fabric: FabricSpec, cfg: SyncConfig,
-                   shape: Sequence[int], scatter_dim: int = 0,
-                   dtype: str = "float32",
-                   fast_axes: Optional[Sequence[str]] = None,
-                   fast_sizes: Optional[Sequence[int]] = None
-                   ) -> CommSchedule:
-    """Build the schedule for one Section from ``(FabricSpec, SyncConfig,
-    shape)``.
-
-    ``fast_axes`` / ``fast_sizes`` override the fabric's fast-tier axis
-    names / extents when the mesh truth differs from the hardware
-    description (the planner's ``fast_axis_sizes`` escape hatch)."""
-    fab_fast = list(fabric.fast_tiers)
-    axes = list(fast_axes) if fast_axes is not None \
-        else [t.axis for t in fab_fast]
-    if fast_sizes is not None:
-        sizes_list = [int(s) for s in fast_sizes]
-    else:
-        sizes_list = [t.size for t in fab_fast]
-    if len(axes) != len(sizes_list):
-        # mesh said N fast tiers but the fabric describes M: trust the mesh
-        # axis list and pad names generically
-        while len(axes) < len(sizes_list):
-            axes.append(f"fast{len(axes)}")
-        axes = axes[:len(sizes_list)]
-    sizes = dict(zip(axes, sizes_list))
-    names = {}
-    for i, a in enumerate(axes):
-        names[a] = fab_fast[i].name if i < len(fab_fast) else a
+def _fabric_axes(fabric: FabricSpec,
+                 fast_axes: Optional[Sequence[Tuple[str, int]]]):
+    """``(fast axis names, {axis: size}, {axis: tier name}, slow axis)`` of
+    a schedule on ``fabric``.  ``fast_axes`` is the mesh's ordered
+    ``(name, size)`` fast DP axes, fastest first (the planner's own); None
+    takes the fabric's fast tiers.  A leg's tier is the fabric tier on its
+    axis, or the axis itself where the fabric has none."""
+    if fast_axes is None:
+        fast_axes = [(t.axis, t.size) for t in fabric.fast_tiers]
+    axes = [a for a, _ in fast_axes]
+    sizes = {a: int(n) for a, n in fast_axes}
+    tier_of = {t.axis: t.name for t in fabric.tiers}
+    names = {a: tier_of.get(a, a) for a in axes}
     slow_axis = fabric.slow_axis
     if slow_axis is not None:
         sizes[slow_axis] = fabric.slowest.size
         names[slow_axis] = fabric.slowest.name
+    return axes, sizes, names, slow_axis
+
+
+def build_schedule(fabric: FabricSpec, cfg: SyncConfig,
+                   shape: Sequence[int], scatter_dim: int = 0,
+                   dtype: str = "float32",
+                   fast_axes: Optional[Sequence[Tuple[str, int]]] = None
+                   ) -> CommSchedule:
+    """Build the schedule for one Section from ``(FabricSpec, SyncConfig,
+    shape)``, its fast legs on ``fast_axes`` (``(name, size)`` pairs,
+    fastest first; default: the fabric's fast tiers)."""
+    axes, sizes, names, slow_axis = _fabric_axes(fabric, fast_axes)
     return schedule_from_axes(axes, slow_axis, cfg, shape, scatter_dim,
                               sizes, dtype, tier_names=names)
 
@@ -823,34 +820,14 @@ def all_to_all_from_axes(fast_axes: Sequence[str], slow_axis: Optional[str],
 
 def build_all_to_all(fabric: FabricSpec, cfg: SyncConfig,
                      shape: Sequence[int], dtype: str = "float32",
-                     fast_axes: Optional[Sequence[str]] = None,
-                     fast_sizes: Optional[Sequence[int]] = None,
+                     fast_axes: Optional[Sequence[Tuple[str, int]]] = None,
                      dest_sizes: Optional[Sequence[float]] = None
                      ) -> CommSchedule:
     """Build the all-to-all schedule for one exchange from ``(FabricSpec,
     SyncConfig, shape)`` — the ``kind="all_to_all"`` twin of
-    :func:`build_schedule`; same ``fast_axes`` / ``fast_sizes`` escape
-    hatch for meshes that differ from the hardware description.
+    :func:`build_schedule`, with the same ``fast_axes``.
     ``dest_sizes`` (per-member wire bytes, slow-major) makes the
     exchange non-uniform — see :func:`all_to_all_from_axes`."""
-    fab_fast = list(fabric.fast_tiers)
-    axes = list(fast_axes) if fast_axes is not None \
-        else [t.axis for t in fab_fast]
-    if fast_sizes is not None:
-        sizes_list = [int(s) for s in fast_sizes]
-    else:
-        sizes_list = [t.size for t in fab_fast]
-    if len(axes) != len(sizes_list):
-        while len(axes) < len(sizes_list):
-            axes.append(f"fast{len(axes)}")
-        axes = axes[:len(sizes_list)]
-    sizes = dict(zip(axes, sizes_list))
-    names = {}
-    for i, a in enumerate(axes):
-        names[a] = fab_fast[i].name if i < len(fab_fast) else a
-    slow_axis = fabric.slow_axis
-    if slow_axis is not None:
-        sizes[slow_axis] = fabric.slowest.size
-        names[slow_axis] = fabric.slowest.name
+    axes, sizes, names, slow_axis = _fabric_axes(fabric, fast_axes)
     return all_to_all_from_axes(axes, slow_axis, cfg, shape, sizes, dtype,
                                 tier_names=names, dest_sizes=dest_sizes)

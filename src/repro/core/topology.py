@@ -384,19 +384,18 @@ def fabric_from_mesh_sizes(sizes: Dict[str, int],
     NICs and stripe cross-tier traffic too) = ICI, "host" = rack-level CXL
     fabric, "pod" = inter-rack Ethernet.  Axes absent from ``sizes`` or of
     size 1 are skipped, so the same code path yields 1-, 2- and 3-tier
-    fabrics."""
+    fabrics; a "pod" tier with nothing faster keeps a one-chip ICI tier
+    below it, so the pod axis stays the slowest tier."""
     hw = hw or HardwareSpec()
     tiers = []
-    n_ici = sizes.get("data", 1) * sizes.get("model", 1)
-    if n_ici > 1:
-        tiers.append(Tier("ici", "data", n_ici, hw.ici_bw, hw.ici_latency))
     if sizes.get("host", 1) > 1:
         tiers.append(Tier("cxl", "host", sizes["host"], hw.cxl_bw, hw.cxl_latency))
     if sizes.get("pod", 1) > 1:
         tiers.append(Tier("dcn", "pod", sizes["pod"], hw.dcn_bw, hw.dcn_latency,
                           lanes=dcn_lanes))
-    if not tiers:
-        tiers = [Tier("ici", "data", 1, hw.ici_bw, hw.ici_latency)]
+    n_ici = sizes.get("data", 1) * sizes.get("model", 1)
+    if n_ici > 1 or not tiers or tiers[0].axis == "pod":
+        tiers.insert(0, Tier("ici", "data", n_ici, hw.ici_bw, hw.ici_latency))
     return FabricSpec(tiers=tuple(tiers), hw=hw)
 
 
@@ -474,18 +473,6 @@ def as_fabric(topo) -> FabricSpec:
     if isinstance(topo, FabricSpec):
         return topo
     return topo.fabric
-
-
-def topology_from_mesh_sizes(sizes: Dict[str, int]):
-    """Default hardware description for a mesh: an N-tier FabricSpec when
-    a rack-level "host" axis is present, else the legacy TwoTierTopology
-    (pod_shape = all non-pod axes)."""
-    if sizes.get("host", 1) > 1:
-        return fabric_from_mesh_sizes(sizes)
-    return TwoTierTopology(
-        num_pods=sizes.get("pod", 1),
-        pod_shape=tuple(s for a, s in sizes.items()
-                        if a not in ("pod", "host")) or (1,))
 
 
 # canonical production topologies per the brief

@@ -16,7 +16,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import SHAPES, ArchConfig, ShapeConfig, get_arch, shape_applicable
-from repro.core.topology import TwoTierTopology, topology_from_mesh_sizes
+from repro.core.topology import TwoTierTopology
 from repro.models.registry import Model, build_model
 from repro.models.transformer import ModelSettings
 from repro.optim import grad_sync
@@ -107,8 +107,6 @@ def build_cell(arch_name: str, shape_name: str, mesh: Mesh, *,
     if not ok:
         raise ValueError(f"skip: {why}")
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    if topo is None:
-        topo = topology_from_mesh_sizes(sizes)
     st = cell_settings(arch, shape, attn_impl=attn_impl)
     ntp = sizes.get("model", 1)
     # repeat-KV layout when heads are TP-sharded but the GQA group factors

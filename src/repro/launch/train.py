@@ -18,7 +18,6 @@ import os
 import jax
 
 from repro.configs.base import SHAPES, ShapeConfig, get_arch, get_smoke_arch
-from repro.core.topology import TwoTierTopology
 from repro.launch.compile_cache import use_compile_cache
 from repro.models.registry import build_model
 from repro.models.transformer import ModelSettings

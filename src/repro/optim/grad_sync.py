@@ -1,17 +1,17 @@
 """DFabric gradient synchronization — the paper's DDP port, plus ZeRO-1.
 
 This module executes a :class:`repro.core.planner.SyncPlan` inside a
-``shard_map`` whose manual axes are the DP domain.  Each Section carries
-the planner-built :class:`~repro.core.schedule.CommSchedule`, which is
-threaded straight into the executor (``collectives.lower_all_reduce``) —
-no tier plan is re-derived here; ``SyncConfig`` is only the fallback
-constructor when the in-trace shape differs from the planned one (the
-non-nested TP path sees model-global shapes).  The fast side of the
-domain is an ORDERED tuple of tiers (``SyncSettings.fast_axes``, fastest
-first — e.g. ``("data", "host")`` for intra-host ICI then rack-level CXL);
-the slowest tier (``slow_axis`` == "pod", the DCN / Ethernet leg) is where
-the NIC pool stripes.  Single-fast-axis (two-tier) call sites keep working
-through the legacy ``fast_axis`` field.
+``shard_map`` whose manual axes are the DP domain; when the mesh has a
+TP axis ("model") the train step nests it in a second, model-manual
+``shard_map``, so every operand has the per-TP-shard shape the planner
+planned.  Each Section carries the planner-built
+:class:`~repro.core.schedule.CommSchedule`, which is handed to the
+executor (``collectives.dfabric_reduce_scatter`` /
+``dfabric_all_reduce``) and lowered as it is: no tier plan is re-derived
+here.  The fast side of the domain is an ORDERED tuple of tiers
+(``SyncSettings.fast_axes``, fastest first — e.g. ``("data", "host")``
+for intra-host ICI then rack-level CXL); the slowest tier (``slow_axis``
+== "pod", the DCN / Ethernet leg) is where the NIC pool stripes.
 
 Two modes:
 
@@ -42,8 +42,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.core.collectives import (dfabric_all_gather, dfabric_all_reduce,
-                                    dfabric_reduce_scatter, pod_psum,
-                                    slow_split)
+                                    dfabric_reduce_scatter, slow_split)
 from repro.utils.jax_compat import axis_size
 from repro.core.planner import Section, SyncPlan
 from repro.optim.adamw import AdamWConfig, adamw_leaf
@@ -89,12 +88,12 @@ def bucket_padded_numel(sec: Section, n_fast: int) -> int:
 class SyncSettings:
     """DP-domain axis layout of one sync plan.
 
-    ``fast_axes`` is the ordered fast-tier axis list (fastest first); when
-    None, the legacy single ``fast_axis`` is used.  ``n_fast`` is the
-    PRODUCT of all fast-tier sizes (ZeRO-1 shards are 1/n_fast)."""
+    ``fast_axes`` is the ordered fast-tier axis list (fastest first).
+    ``n_fast`` is the PRODUCT of all fast-tier sizes (ZeRO-1 shards are
+    1/n_fast)."""
 
     mode: str = "zero1"  # "paper" | "zero1"
-    fast_axis: str = "data"
+    fast_axes: Tuple[str, ...] = ("data",)  # ordered, fastest first
     slow_axis: Optional[str] = "pod"
     n_fast: int = 1
     n_slow: int = 1
@@ -102,19 +101,13 @@ class SyncSettings:
     # shard_map (§Perf iteration 6): TP-sharded sections then psum their
     # sq-norms over this axis too
     model_axis: Optional[str] = None
-    fast_axes: Optional[Tuple[str, ...]] = None  # ordered, fastest first
-
-    @property
-    def fast(self) -> Tuple[str, ...]:
-        """All fast-tier axes, fastest first."""
-        return self.fast_axes if self.fast_axes else (self.fast_axis,)
 
     @property
     def fast_entry(self):
         """PartitionSpec entry for a dim scattered over the fast tiers:
         the bare axis name for one tier, the ordered tuple for several
         (fastest-major, matching dfabric_reduce_scatter ownership)."""
-        f = self.fast
+        f = self.fast_axes
         return f if len(f) > 1 else f[0]
 
     @property
@@ -126,7 +119,7 @@ def flat_fast_index(ss: SyncSettings):
     """This rank's flattened index over the fast tiers, fastest-tier-major
     (matches the ownership order of ``dfabric_reduce_scatter``)."""
     idx = None
-    for a in ss.fast:
+    for a in ss.fast_axes:
         i = lax.axis_index(a)
         idx = i if idx is None else idx * axis_size(a) + i
     return idx if idx is not None else jnp.int32(0)
@@ -135,7 +128,8 @@ def flat_fast_index(ss: SyncSettings):
 def full_depth(sec: Section, ss: SyncSettings) -> bool:
     """The ZeRO-1 fused path owns a 1/n_fast shard, which requires the
     section's tier plan to scatter over EVERY fast tier."""
-    return sec.sync.scatter_depth < 0 or sec.sync.scatter_depth >= len(ss.fast)
+    d = sec.sync.scatter_depth
+    return d < 0 or d >= len(ss.fast_axes)
 
 
 def section_kind(sec: Section, ss: SyncSettings) -> str:
@@ -249,8 +243,8 @@ def _scattered_axes(sec: Section, ss: SyncSettings) -> Tuple[str, ...]:
     the first ``scatter_depth`` entries of the ordered fast-axis list."""
     if sec.sync.strategy != "hier_striped" or sec.scatter_dim < 0:
         return ()
-    d = len(ss.fast) if sec.sync.scatter_depth < 0 else sec.sync.scatter_depth
-    return ss.fast[:d]
+    d = sec.sync.scatter_depth
+    return ss.fast_axes if d < 0 else ss.fast_axes[:d]
 
 
 def inner_state_specs(plan: SyncPlan, param_specs_flat: Dict[str, P],
@@ -344,11 +338,6 @@ def sync_and_update(params, grads, sync_state, plan: SyncPlan,
         zero1 = zero1_path(sec, ss)
         model_axes = ((ss.model_axis,) if (ss.model_axis and sec.model_sharded)
                       else ())
-        # the planner's NIC-pool stagger and memory-pool staging survive
-        # in-trace schedule rebuilds (the non-nested TP path sees
-        # model-global shapes)
-        lane_off = sec.schedule.lane_offset if sec.schedule is not None else 0
-        staging = sec.schedule.staging if sec.schedule is not None else None
         with jax.named_scope("grad_sync"):
             if bucket:
                 g = _bucket_pack(gflat, sec, n_fast)
@@ -358,16 +347,16 @@ def sync_and_update(params, grads, sync_state, plan: SyncPlan,
                 k = max(sec.scatter_dim, 0)
             if zero1:
                 shard, new_ef = dfabric_reduce_scatter(
-                    g, ss.fast, ss.slow_axis, sec.sync, scatter_dim=k, ef=ef,
-                    schedule=sec.schedule, lane_offset=lane_off, staging=staging)
+                    g, ss.fast_axes, ss.slow_axis, sec.sync, scatter_dim=k,
+                    ef=ef, schedule=sec.schedule)
                 shard = shard * inv_dp
                 synced[sec.name] = ("shard", shard, k)
                 sqnorm = sqnorm + lax.psum(jnp.sum(jnp.square(shard)),
-                                           ss.fast + model_axes)
+                                           ss.fast_axes + model_axes)
             else:
                 full, new_ef = dfabric_all_reduce(
-                    g, ss.fast, ss.slow_axis, sec.sync, scatter_dim=k, ef=ef,
-                    schedule=sec.schedule, lane_offset=lane_off, staging=staging)
+                    g, ss.fast_axes, ss.slow_axis, sec.sync, scatter_dim=k,
+                    ef=ef, schedule=sec.schedule)
                 full = full * inv_dp
                 synced[sec.name] = ("full", full, k)
                 sq = jnp.sum(jnp.square(full))
@@ -408,7 +397,7 @@ def sync_and_update(params, grads, sync_state, plan: SyncPlan,
             # the all-gather now carries UPDATED PARAMETERS (fused ZeRO-1);
             # gathers run up the fast tiers in reverse scatter order
             with jax.named_scope("grad_sync"):
-                gathered = dfabric_all_gather(new_p_sh, ss.fast,
+                gathered = dfabric_all_gather(new_p_sh, ss.fast_axes,
                                               gather_dim=(0 if bucket else k))
                 if bucket:
                     new_flat.update(_bucket_unpack(gathered, sec, pflat))

@@ -28,7 +28,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig, ShapeConfig
 from repro.core.planner import Planner, SyncPlan
-from repro.core.topology import TwoTierTopology, topology_from_mesh_sizes
+from repro.core.topology import fabric_from_mesh_sizes
 from repro.utils import jax_compat
 from repro.models.registry import Model
 from repro.models.sharding import MeshInfo
@@ -78,23 +78,29 @@ def batch_sharding(mesh: Mesh, model: Model, mi: MeshInfo):
 # ---------------------------------------------------------------------------
 
 
-def make_sync_plan(model: Model, mesh: Mesh, topo, *,  # topo: TwoTierTopology | FabricSpec
+def make_sync_plan(model: Model, mesh: Mesh, topo=None, *,
                    codec: Optional[str] = None, strategy: str = "auto",
                    bucket_bytes: int = 4 << 20,
                    embed_tp: bool = True,
                    pipeline: bool = True,
                    mid_codec: Optional[str] = None) -> Tuple[SyncPlan, SyncSettings]:
+    """Plan the gradient sync of ``model`` on ``mesh``: the one place the
+    mesh's axes are bound to the sync.  The planner prices on ``topo``
+    (TwoTierTopology | FabricSpec; None: ``fabric_from_mesh_sizes`` of
+    the mesh) and plans every schedule on the mesh's own fast DP axes,
+    which the executor lowers as planned."""
     mi = mesh_info(mesh, embed_tp=embed_tp)
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    if topo is None:
+        topo = fabric_from_mesh_sizes(sizes)
     fast_axes = fast_axes_of(sizes) or ("data",)
     fast_sizes = tuple(sizes.get(a, 1) for a in fast_axes)
     n_fast = int(np.prod(fast_sizes))
     n_slow = sizes.get("pod", 1)
-    ss = SyncSettings(mode="zero1", fast_axis=fast_axes[0],
+    ss = SyncSettings(mode="zero1", fast_axes=fast_axes,
                       slow_axis="pod" if "pod" in sizes else None,
                       n_fast=n_fast, n_slow=n_slow,
-                      model_axis="model" if "model" in sizes else None,
-                      fast_axes=fast_axes)
+                      model_axis="model" if "model" in sizes else None)
     shapes = tree_paths(model.param_shapes())
     specs = tree_paths(model.param_specs(mi))
     avoid = {p: frozenset(i for i, s in enumerate(sp) if s is not None)
@@ -111,8 +117,8 @@ def make_sync_plan(model: Model, mesh: Mesh, topo, *,  # topo: TwoTierTopology |
         return tuple(sh)
 
     local = {p: local_shape(p) for p in shapes}
-    planner = Planner(topo, fast_axis_sizes=fast_sizes, codec=codec,
-                      strategy=strategy, pipeline=pipeline,
+    planner = Planner(topo, fast_axes=tuple(zip(fast_axes, fast_sizes)),
+                      codec=codec, strategy=strategy, pipeline=pipeline,
                       mid_codec=mid_codec)
     plan = planner.plan(shapes, bucket_bytes=bucket_bytes, avoid_dims=avoid,
                         local_shapes=local)
@@ -136,7 +142,7 @@ def make_dfabric_train_step(model: Model, mesh: Mesh, plan: SyncPlan,
     if not zero1:
         ss = dataclasses.replace(ss, mode="paper")
     arch = model.arch
-    manual = set(ss.fast) | ({ss.slow_axis} if ss.slow_axis else set())
+    manual = set(ss.fast_axes) | ({ss.slow_axis} if ss.slow_axis else set())
     dp_axes = tuple(a for a in DP_MESH_AXES if a in manual)
     dp_spec = dp_axes if len(dp_axes) > 1 else dp_axes[0]
     pshapes = model.param_shapes()
@@ -389,14 +395,12 @@ class Trainer:
     """End-to-end training driver with checkpoint/restart + preemption."""
 
     def __init__(self, model: Model, mesh: Mesh, shape: ShapeConfig,
-                 cfg: TrainerConfig, topo=None,  # TwoTierTopology | FabricSpec
+                 cfg: TrainerConfig, topo=None,  # None: from the mesh
                  data_pipeline=None):
         from repro.checkpoint.manager import CheckpointManager
         from repro.data.pipeline import DataConfig, TokenPipeline
 
         self.model, self.mesh, self.shape, self.cfg = model, mesh, shape, cfg
-        sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-        self.topo = topo if topo is not None else topology_from_mesh_sizes(sizes)
         self.pipeline = data_pipeline or TokenPipeline(
             model.arch, shape, DataConfig(seed=cfg.seed))
         opt_cfg = AdamWConfig()
@@ -404,7 +408,7 @@ class Trainer:
         lr_fn = cosine_schedule(cfg.lr, cfg.warmup, cfg.steps)
         self.mi = mesh_info(mesh, fsdp=(cfg.mode == "gspmd"))
         if cfg.mode == "dfabric":
-            self.plan, self.ss = make_sync_plan(model, mesh, self.topo,
+            self.plan, self.ss = make_sync_plan(model, mesh, topo,
                                                 codec=cfg.codec,
                                                 pipeline=cfg.pipeline)
             self.step_fn, self._init_state, self.state_sharding = \

@@ -75,14 +75,15 @@ grads_global = {"w": rng.standard_normal((4, 8, 16)).astype(np.float32),
 
 topo = TwoTierTopology(num_pods=2, pod_shape=(2, 2))
 shapes = {k: jax.ShapeDtypeStruct(v.shape, v.dtype) for k, v in params.items()}
-planner = Planner(topo, fast_axis_size=2, strategy="hier_striped")
+planner = Planner(topo, fast_axes=(("data", 2),), strategy="hier_striped")
 plan = planner.plan(shapes, bucket_bytes=128)  # w becomes its own section
 opt_cfg = AdamWConfig(weight_decay=0.0, grad_clip=0.0)
 
 
 outs = {}
 for mode in ("zero1", "paper"):
-    ss = SyncSettings(mode=mode, fast_axis="data", slow_axis="pod", n_fast=2, n_slow=2)
+    ss = SyncSettings(mode=mode, fast_axes=("data",), slow_axis="pod",
+                      n_fast=2, n_slow=2)
     state = grad_sync.init_sync_state(plan, shapes, ss)
     specs = grad_sync.sync_state_specs(plan, shapes, ss)
 

@@ -138,8 +138,8 @@ opt_cfg = AdamWConfig(weight_decay=0.0, grad_clip=0.0)
 
 outs = {}
 for mode in ("zero1", "paper"):
-    ss = SyncSettings(mode=mode, fast_axis="data", slow_axis="pod",
-                      n_fast=4, n_slow=2, fast_axes=("data", "host"))
+    ss = SyncSettings(mode=mode, fast_axes=("data", "host"), slow_axis="pod",
+                      n_fast=4, n_slow=2)
     state = grad_sync.init_sync_state(plan, shapes, ss)
     specs = grad_sync.sync_state_specs(plan, shapes, ss)
 
@@ -163,5 +163,104 @@ for k in params:
     d = np.max(np.abs(outs["zero1"][k] - outs["paper"][k]))
     assert d < 1e-5, (k, d)
 print("3-tier zero1 == paper update OK")
+
+# ---- the train step's planned schedules lower exactly as planned ------------
+
+from repro.configs import get_smoke_arch  # noqa: E402
+from repro.core.schedule import CommSchedule  # noqa: E402
+from repro.models import ModelSettings, build_model  # noqa: E402
+from repro.runtime.train_loop import (Trainer, TrainerConfig,  # noqa: E402
+                                      make_sync_plan)
+
+ST = ModelSettings(param_dtype="float32", compute_dtype="float32",
+                   remat="none", loss_chunk=16, max_seq=64)
+model = build_model(get_smoke_arch("qwen2-0.5b"), ST)
+for shape in ((1, 4, 1), (2, 2, 1)):
+    mesh = jax_compat.make_mesh(shape, ("pod", "data", "model"),
+                                devices=jax.devices()[:shape[0] * shape[1]])
+    plan, ss = make_sync_plan(model, mesh, bucket_bytes=1 << 13)
+    members = P(("pod", "data"))
+    xs = [rng.standard_normal((shape[0] * shape[1],) + sec.schedule.shape)
+          .astype(np.float32) for sec in plan.sections]
+    logs = {}
+
+    def lower_all(*ops):
+        outs = []
+        for sec, op in zip(plan.sections, ops):
+            op = op[0]
+            k = max(sec.scatter_dim, 0)
+            logs[sec.name] = ar_log, rs_log = [], None
+            full, _ = dfabric_all_reduce(op, ss.fast_axes, ss.slow_axis,
+                                         sec.sync, scatter_dim=k,
+                                         schedule=sec.schedule,
+                                         leg_log=ar_log)
+            outs.append(full[None])
+            if grad_sync.zero1_path(sec, ss):
+                rs_log = []
+                logs[sec.name] = ar_log, rs_log
+                shard, _ = dfabric_reduce_scatter(
+                    op, ss.fast_axes, ss.slow_axis, sec.sync, scatter_dim=k,
+                    schedule=sec.schedule, leg_log=rs_log)
+                outs.append(dfabric_all_gather(shard, ss.fast_axes, k)[None])
+        return tuple(outs)
+
+    g = jax.jit(jax_compat.shard_map(
+        lower_all, mesh=mesh, in_specs=(members,) * len(xs),
+        out_specs=members, check_vma=False))
+    outs = iter(g(*[jax.device_put(x, NamedSharding(mesh, members))
+                    for x in xs]))
+    for sec, x in zip(plan.sections, xs):
+        ar_log, rs_log = logs[sec.name]
+        assert ar_log == list(sec.schedule.legs), (sec.name, ar_log)
+        want = np.broadcast_to(x.sum(0), x.shape)
+        np.testing.assert_allclose(np.asarray(next(outs)), want,
+                                   rtol=1e-5, atol=1e-5)
+        if rs_log is not None:
+            s = sec.schedule
+            assert rs_log == list(s.down_legs + s.slow_legs), (sec.name,
+                                                               rs_log)
+            np.testing.assert_allclose(np.asarray(next(outs)), want,
+                                       rtol=1e-5, atol=1e-5)
+    print(f"mesh {shape}: {len(plan.sections)} planned schedules lower "
+          f"leg for leg as planned OK")
+
+# a schedule that does not fit its operand raises, naming both
+sec = plan.sections[0]
+wrong = CommSchedule(sec.schedule.legs, (3,) + sec.schedule.shape,
+                     scatter_dim=sec.schedule.scatter_dim,
+                     strategy=sec.schedule.strategy, cfg=sec.schedule.cfg)
+for fn in (dfabric_all_reduce, dfabric_reduce_scatter):
+    for sched, slow in ((wrong, "pod"), (sec.schedule, None)):
+        try:
+            fn(jnp.zeros(sec.schedule.shape), ("data",), slow, sec.sync,
+               scatter_dim=max(sec.scatter_dim, 0), schedule=sched)
+        except ValueError as e:
+            assert "does not fit" in str(e), e
+        else:
+            raise AssertionError(f"{fn.__name__} lowered a schedule that "
+                                 f"does not fit: {sched.describe()}")
+print("a schedule of the wrong shape or axes raises OK")
+
+# a (pod, host, data) = (2, 2, 2) plan scatters every bucket to 1/n_fast
+mesh3 = jax_compat.make_mesh((2, 2, 2, 1), ("pod", "host", "data", "model"))
+
+
+class Shape:
+    global_batch, seq_len = 8, 32
+    name, kind = "t", "train"
+
+
+tr = Trainer(model, mesh3, Shape(), TrainerConfig(
+    steps=1, warmup=1, log_every=0, mode="dfabric"))
+buckets = [s for s in tr.plan.sections if len(s.leaf_paths) > 1]
+assert buckets
+for sec in buckets:
+    assert sec.sync.scatter_depth == -1, sec
+    assert sec.schedule.scattered_axes == ("data", "host"), \
+        sec.schedule.describe()
+out = tr.train()
+assert out["step"] == 1 and np.isfinite(out["metrics"][-1]["loss"])
+print(f"3-tier plan: {len(buckets)} buckets at full depth, one step "
+      f"trains OK")
 
 print("ALL OK")

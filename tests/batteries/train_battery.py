@@ -54,8 +54,8 @@ model = build_model(get_smoke_arch("qwen2-0.5b"), ST)
 cfg = TrainerConfig(steps=8, lr=8e-3, warmup=2, log_every=0, seed=3,
                     mode="dfabric", zero1=True)
 tr = Trainer(model, mesh3, Shape(), cfg)
-from repro.core.topology import FabricSpec  # noqa: E402
-assert isinstance(tr.topo, FabricSpec) and tr.topo.depth == 3
+legs = {(l.tier, l.axis) for s in tr.plan.sections for l in s.schedule.legs}
+assert {("ici", "data"), ("cxl", "host"), ("dcn", "pod")} <= legs, legs
 assert tr.ss.fast_axes == ("data", "host") and tr.ss.n_fast == 4
 assert any(s.sync.scatter_depth != 0 for s in tr.plan.sections)
 out = tr.train()

@@ -288,7 +288,7 @@ def test_moe_dispatch_schedule_honors_planner_mesh_override():
 
     arch = get_smoke_arch("deepseek-moe-16b")  # E = 8
     # fabric says 2x2x2 = 8 members, the mesh override says 2*2 = 4
-    pl = Planner(FAB3, fast_axis_sizes=(2,), min_chunk_numel=1 << 4)
+    pl = Planner(FAB3, fast_axes=(("data", 2),), min_chunk_numel=1 << 4)
     assert pl.domain_size == 4
     s = moe_dispatch_schedule(arch, 64, pl)
     assert s.shape[0] == 4

@@ -165,7 +165,7 @@ def test_for_fabric_rederives_fast_sizes():
     deg = fab.degrade(tier_members={"ici": 1})
     assert planner.for_fabric(deg).fast_sizes != planner.fast_sizes
     # explicit override survives the move to the degraded fabric
-    pinned = Planner(fab, fast_axis_sizes=(2, 2), max_chunks=4)
+    pinned = Planner(fab, fast_axes=(("data", 2), ("host", 2)), max_chunks=4)
     assert pinned.for_fabric(deg).fast_sizes == (2, 2)
 
 

@@ -10,6 +10,7 @@ Covers the PR's acceptance criteria:
     ``grad_sync`` consumes end-to-end,
   * ``TwoTierTopology`` compatibility surface is unchanged.
 """
+import json
 import os
 
 import jax
@@ -172,11 +173,12 @@ def test_planner_cost_monotone_in_slow_bw():
 
 
 def test_planner_two_tier_call_sites_unchanged():
-    """Legacy TwoTierTopology planner construction keeps working."""
+    """A TwoTierTopology planner on the mesh's own fast axis keeps
+    working."""
     from repro.core.planner import Planner
     from repro.core.topology import TwoTierTopology
     topo = TwoTierTopology(num_pods=2, pod_shape=(2, 2))
-    planner = Planner(topo, fast_axis_size=2, strategy="hier_striped")
+    planner = Planner(topo, fast_axes=(("data", 2),), strategy="hier_striped")
     plan = planner.plan({"w": jax.ShapeDtypeStruct((8, 16), jnp.float32)},
                         bucket_bytes=1)
     assert plan.sections[0].sync.scatter_depth == -1
@@ -315,8 +317,8 @@ def test_planner_stores_schedule_on_sections():
 
 def test_planner_bucket_chunks_not_hardcoded():
     """Regression: flush() used to hard-code chunks=1 for small-leaf
-    buckets; now the searched chunk count (clamped by _adjust_chunks)
-    lands in the emitted Section."""
+    buckets; now the searched chunk count (clamped by the schedule
+    builder) lands in the emitted Section."""
     from repro.core.planner import Planner
     fab = _fabric3()
     planner = Planner(fab, strategy="hier_striped", max_chunks=4)
@@ -390,6 +392,26 @@ def test_planner_mid_tier_codec_legal():
 def test_multi_device_ntier_battery():
     out = run_multi_device(os.path.join(HERE, "batteries", "ntier_battery.py"))
     assert "ALL OK" in out
+
+
+@pytest.fixture(scope="module")
+def planned_axes():
+    out = run_multi_device(os.path.join(HERE, "batteries",
+                                        "sync_plan_axes.py"))
+    return json.loads(out.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("shape", [(1, 2, 1), (1, 4, 1), (1, 2, 2),
+                                   (2, 2, 1)], ids=str)
+def test_sync_plan_names_only_mesh_axes(planned_axes, shape):
+    """``make_sync_plan`` plans every Section on the mesh's own axes, so
+    the executor lowers the schedule the planner priced (one-host data
+    parallelism included: ``pod`` = 1, ``data`` > 1)."""
+    got = planned_axes[str(list(shape))]
+    assert got["sections"]
+    for name, axes in got["sections"].items():
+        assert set(axes) <= set(got["mesh_axes"]), (name, axes)
+    assert any("data" in axes for axes in got["sections"].values())
 
 
 def test_multi_device_schedule_battery():

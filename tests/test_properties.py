@@ -311,7 +311,7 @@ def test_all_to_all_legs_cover_domain_once(sizes, seed):
 def test_planner_covers_all_leaves_once(dims, seed):
     shapes = {f"p{i}": jax.ShapeDtypeStruct((a, b), jnp.float32)
               for i, (a, b) in enumerate(dims)}
-    plan = Planner(TOPO, fast_axis_size=16).plan(shapes, bucket_bytes=1 << 14)
+    plan = Planner(TOPO, fast_axes=(("data", 16),)).plan(shapes, bucket_bytes=1 << 14)
     covered = [p for sec in plan.sections for p in sec.leaf_paths]
     assert sorted(covered) == sorted(shapes)
     for sec in plan.sections:
@@ -325,7 +325,7 @@ def test_planner_covers_all_leaves_once(dims, seed):
 
 def test_planner_avoid_dims():
     shapes = {"w": jax.ShapeDtypeStruct((64, 160), jnp.float32)}
-    pl = Planner(TOPO, fast_axis_size=16)
+    pl = Planner(TOPO, fast_axes=(("data", 16),))
     plan = pl.plan(shapes, bucket_bytes=1 << 10,
                    avoid_dims={"w": frozenset({1})})
     sec = plan.sections[0]
